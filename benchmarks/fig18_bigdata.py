@@ -75,8 +75,10 @@ def weak_scaling_rows() -> list[Row]:
                XLA_FLAGS="--xla_force_host_platform_device_count=1")
     rows: list[Row] = []
     base_wall = None
+    # No --compile-cache-dir: every worker shares the launcher's fixed
+    # compile cache (JAX_COMPILATION_CACHE_DIR, else .jax_cache/ in the
+    # checkout), so the rows measure the run, not XLA.
     with tempfile.TemporaryDirectory() as tmp:
-        cache = Path(tmp) / "compile-cache"  # shared: measure run, not XLA
         for nprocs in (1, 2, 4):
             with socket.socket() as s:
                 s.bind(("127.0.0.1", 0))
@@ -87,7 +89,6 @@ def weak_scaling_rows() -> list[Row]:
                 "--num-bins", "20", "--slices",
                 *[str(i) for i in range(nprocs)],
                 "--out-dir", str(Path(tmp) / f"out{nprocs}"),
-                "--compile-cache-dir", str(cache),
                 "--num-processes", str(nprocs), "--coordinator", coord,
             ]
             t0 = time.perf_counter()

@@ -145,10 +145,9 @@ def run(quick: bool = True):
                 f"{b/1024:.0f}KiB of 16MiB VMEM ({'ok' if b < 16 * 2**20 else 'OVER'})")
         )
     # Fused fit kernel's TPU tile (one-hot accumulation path, 10 types):
-    # values + freq scratch + edges + params + the strip-mined one-hot.
+    # values + freq scratch + the (T, bp, L) masses + the strip-mined one-hot.
     bp, bn, L, T = 8, 512, 64, 10
-    fb = bp * bn * 4 + bp * L * 4 + bp * (L + 1) * 4 + bp * 3 * T * 4 \
-        + bp * bn * L * 4 // 16
+    fb = bp * bn * 4 + bp * L * 4 + T * bp * L * 4 + bp * bn * L * 4 // 16
     rows.append(
         Row(f"kernel/vmem_fitpdf_{bp}x{bn}", 0.0,
             f"{fb/1024:.0f}KiB of 16MiB VMEM ({'ok' if fb < 16 * 2**20 else 'OVER'})")

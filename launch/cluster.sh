@@ -2,13 +2,18 @@
 # Multi-process cluster launcher (DESIGN.md §17): spawn NPROCS run_pdf
 # workers on this host, each pinned to one seat of the placement
 # (--num-processes/--process-id), sharing one jax.distributed coordinator
-# and one --out-dir. Usage:
+# and one --out-dir. It is the CPU rehearsal of a multi-node topology, and
+# it runs every worker on the CPU (JAX_PLATFORMS=cpu): a chip belongs to
+# one process, so on a host with chips a second worker would fail on the
+# chip's lock. There, one process drives every local chip through
+# execution.placement.shard_devices. Usage:
 #
 #   launch/cluster.sh NPROCS [run_pdf flags...]
 #
 # Every flag after NPROCS is passed through to every worker — give them a
-# shared --out-dir (required in cluster mode) and optionally a shared
-# --compile-cache-dir so only the first launch ever compiles. Environment:
+# shared --out-dir (required in cluster mode). They share one compile cache
+# (JAX_COMPILATION_CACHE_DIR, else --compile-cache-dir, else .jax_cache/ in
+# the checkout), so only the first launch ever compiles. Environment:
 #
 #   COORD_PORT          coordinator port (default 12723)
 #   CLUSTER_REF         a reference out_dir: after the run, verify this
@@ -36,6 +41,7 @@ if [ -f "$TCMALLOC" ]; then
     export TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD=60000000000  # no numpy spam
 fi
 export TF_CPP_MIN_LOG_LEVEL=4                              # no XLA chatter
+export JAX_PLATFORMS=cpu          # one process per chip: workers stay on CPU
 export JAX_ENABLE_X64=0           # f64 runs through the x64-lanes emulation
 export JAX_DEFAULT_DTYPE_BITS=32
 export JAX_NUM_CPU_DEVICES="${CPU_DEVICES_PER_PROC:-1}"

@@ -71,8 +71,7 @@ class SessionReport:
     # runtime.cluster.compile_counters — concurrent sessions in one process
     # share the counters). ``compiles`` fires on persistent-cache hits too
     # (XLA still enters its compile path), so the "zero new traces"
-    # cold-start assertion is ``compile_cache_misses == 0`` with
-    # ``ExecSpec.compile_cache_dir`` enabled.
+    # cold-start assertion is ``compile_cache_misses == 0``.
     traces: int = 0
     compiles: int = 0
     compile_cache_hits: int = 0
@@ -130,15 +129,14 @@ class PDFSession:
         # session across two hashes).
         self._spec_hash = spec.content_hash()
         # Cold-start elimination (DESIGN.md §17): the persistent XLA
-        # compilation cache, keyed under <compile_cache_dir>/<spec_hash> so
-        # a re-launched identical spec serves every executable from disk.
-        # Enabled before any executor compiles; the counter baseline makes
-        # report() deltas session-scoped.
+        # compilation cache (runtime.cluster.enable_compilation_cache picks
+        # the directory), so a re-launched spec serves every executable from
+        # disk. Enabled before any executor compiles; the counter baseline
+        # makes report() deltas session-scoped.
         from repro.runtime import cluster as _cluster
 
-        if spec.execution.compile_cache_dir:
-            _cluster.enable_compilation_cache(
-                spec.execution.compile_cache_dir, self._spec_hash)
+        self.compile_cache_dir = _cluster.enable_compilation_cache(
+            spec.execution.compile_cache_dir)
         self._compile_baseline = _cluster.compile_counters()
         self.cache = (ResultCache(spec.execution.cache_dir,
                                   max_bytes=spec.execution.cache_max_bytes,

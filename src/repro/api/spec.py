@@ -453,9 +453,10 @@ class ExecSpec:
     # (bitwise by the per-slice independence contract) and the compilation
     # cache only skips re-compiling executables that would be identical.
     compile_cache_dir: str | None = field(default=None, metadata=_meta(
-        "persistent XLA compilation cache root: executables cached under "
-        "<dir>/<spec_hash>, so a re-launched identical spec never "
-        "re-compiles (runtime.cluster)", hashed=False, type_=str,
+        "persistent XLA compilation cache directory (default: "
+        "JAX_COMPILATION_CACHE_DIR when set, else .jax_cache/ in the "
+        "checkout), so a re-launched spec never re-compiles "
+        "(runtime.cluster)", hashed=False, type_=str,
         flag="--compile-cache-dir"))
     placement: PlacementSpec = field(default=PlacementSpec(), metadata=_meta(
         "multi-process placement (see execution.placement)", hashed=False))

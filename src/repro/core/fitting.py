@@ -25,9 +25,10 @@ implemented (``FIT_BACKENDS``):
 * ``kernels``   — the chain with the Pallas moments + histogram kernels
   swapped in (two kernel launches, masses still materialized in XLA).
 * ``fused``     — the single-launch path (``kernels/fitpdf``): one kernel
-  emits moments + Eq.-5 edges, a second streams the window once more and
-  reduces histogram, CDF masses and Eq.-5 error in its epilogue, so only
-  the (P, T) errors reach HBM. The default executor path.
+  emits moments + Eq.-5 edges, a second streams the window once more,
+  builds the histogram in VMEM and reduces the Eq.-5 error against the
+  (small, XLA-evaluated) CDF masses in its epilogue, so only the (P, T)
+  errors reach HBM. The default executor path.
 
 ``mode='faithful'`` deliberately keeps the per-type chain structure for
 every backend — a fused single pass cannot represent the paper's per-type

@@ -87,7 +87,7 @@ def quantize_keys(mean: jax.Array, std: jax.Array, tol: float = DEFAULT_TOL) -> 
     ``std`` is quantized as given; use :func:`quantize_keys_from_var` when
     only the variance is at hand (it reproduces the host's f64 sqrt).
     """
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         # asarray inside the context: a float64 numpy input must stay f64
         # (outside, canonicalization would round it to f32 before the
         # widening — the aliasing class this function exists to eliminate).
@@ -109,7 +109,7 @@ def quantize_keys_from_var(
     """Quantize from (mean, var) exactly as the host Select path does:
     clamp, then sqrt in float64 (clamping commutes with the exact widening
     cast, and both paths' sqrt is correctly rounded f64)."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         var = jnp.asarray(var)  # inside the context: f64 inputs stay f64
         # dtype-preserving zero built from a 32-bit literal (a 64-bit zero
         # constant would be canonicalized at an x64-off lowering)

@@ -1,8 +1,7 @@
 """Pallas TPU kernels: the fused single-launch fit path.
 
-Two kernels replace the chained moments -> histogram -> (P, T, L) CDF-mass
-tensor -> Eq.-5 reduction device computations of ComputePDF&Error
-(Algorithms 3-4):
+Two kernels replace the chained moments -> histogram -> Eq.-5 reduction
+device computations of ComputePDF&Error (Algorithms 3-4):
 
 * ``moments_edges_stats`` — the streaming-moments kernel extended to also
   emit the Eq.-5 interval edges from its final min/max, so callers that
@@ -10,17 +9,21 @@ tensor -> Eq.-5 reduction device computations of ComputePDF&Error
   fit, tests) get it from the same single pass over the data.
 * ``fit_error_counts`` — histogram + error: streams the raw window once,
   accumulates the ``(bp, L)`` frequency block in a VMEM scratch, and —
-  with that block still resident — the last obs-chunk's epilogue
-  evaluates every candidate type's CDF masses at the edges and reduces
-  the Eq.-5 L1 error. Only the ``(P, T)`` error matrix reaches HBM: the
-  ``(P, n, L)`` one-hot, the ``(P, T, L)`` masses tensor and the
+  with that block still resident — the last obs-chunk's epilogue reduces
+  the Eq.-5 L1 error against every candidate type's CDF masses. Only the
+  ``(P, T)`` error matrix reaches HBM: the ``(P, n, L)`` one-hot and the
   ``(P, L)`` frequency round-trip of the chained path never exist. The
-  ``(P, L+1)`` edges ride along as an *input* (~L/n of the data volume)
-  rather than being re-derived in-register: the in-kernel formula compiles
-  1 ulp away from the XLA ``interval_edges``, and f32 ``gammainc`` at the
-  huge shape parameters the gamma fitter produces for near-normal windows
-  amplifies 1 ulp of edge into ~5e-2 of Eq.-5 error — bit-identical edges
-  keep every backend's errors allclose at normal f32 tolerances.
+  ``(T, P, L)`` masses ride along as an *input* (T*L/n of the window's
+  bytes, ~0.3 MB of a 25 MB Set1 window), computed by the jitted wrapper
+  in XLA with the reference ``pdf_error.cdf_masses``. The CDFs need
+  ``erf`` (normal, lognormal) and the incomplete gamma/beta functions
+  (10 types), none of which Mosaic lowers; evaluating them outside keeps
+  the kernel free of every special function, and keeps its masses
+  bit-identical to the reference backend's (f32 ``gammainc`` at the huge
+  shape parameters the gamma fitter produces for near-normal windows
+  amplifies 1 ulp of edge into ~5e-2 of Eq.-5 error). The masses are laid
+  out type-major so the epilogue reads each type's ``(bp, L)`` tile by a
+  leading-axis index.
 
 The histogram accumulation strategy is a static switch: compare-and-sum
 one-hot for the Mosaic TPU path (same scheme as kernels/hist), and a
@@ -42,8 +45,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.core import distributions as dists
 
 NUM_STATS = 8  # mean, var(unbiased), skew, kurt, min, max, (2 pad lanes)
 _EPS = 1e-12
@@ -150,13 +151,12 @@ def moments_edges_stats(
 def _fit_error_kernel(
     n_valid: int,
     num_bins: int,
-    types: tuple[str, ...],
+    num_types: int,
     matmul_hist: bool,
     x_ref,
     lo_ref,
     hi_ref,
-    edges_ref,
-    params_ref,
+    masses_ref,
     err_ref,
     freq_ref,
 ):
@@ -206,46 +206,35 @@ def _fit_error_kernel(
 
     @pl.when(j == nj - 1)
     def _epilogue():
-        # Frequency block still VMEM-resident: evaluate every candidate
-        # type's CDF masses at the edges and the Eq.-5 error in-register.
-        freq = freq_ref[...]  # (bp, L)
-        rel = freq / jnp.float32(max(n_valid, 1))
-        edges = edges_ref[...]  # (bp, L+1)
-        errs = []
-        for t, name in enumerate(types):
-            pk = jnp.stack(
-                [params_ref[:, 3 * t + s] for s in range(3)], axis=-1
-            )[:, None, :]  # (bp, 1, 3) broadcast against edges (bp, L+1)
-            cdf = dists.cdf(name, pk, edges)  # (bp, L+1)
-            masses = cdf[:, 1:] - cdf[:, :-1]
-            errs.append(jnp.sum(jnp.abs(rel - masses), axis=1))
+        # Frequency block still VMEM-resident: reduce the Eq.-5 error
+        # against each candidate type's (bp, L) masses tile.
+        rel = freq_ref[...] / jnp.float32(max(n_valid, 1))  # (bp, L)
+        errs = [
+            jnp.sum(jnp.abs(rel - masses_ref[t]), axis=1) for t in range(num_types)
+        ]
         err_ref[...] = jnp.stack(errs, axis=1)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "types", "num_bins", "block_points", "block_obs", "interpret", "matmul_hist"
-    ),
+    static_argnames=("num_bins", "block_points", "block_obs", "interpret", "matmul_hist"),
 )
 def fit_error_counts(
     values: jax.Array,
     vmin: jax.Array,
     vmax: jax.Array,
-    edges: jax.Array,
-    params: jax.Array,
-    types: tuple[str, ...],
+    masses: jax.Array,
     num_bins: int,
     block_points: int = 8,
     block_obs: int = 512,
     interpret: bool = False,
     matmul_hist: bool = False,
 ) -> jax.Array:
-    """values (P, n), vmin/vmax (P,), edges (P, L+1), params (P, T, 3)
-    -> Eq.-5 errors (P, T). P % block_points == 0 required (ops.py pads);
-    n masked in-kernel."""
+    """values (P, n), vmin/vmax (P,), masses (T, P, L) -> Eq.-5 errors
+    (P, T). P % block_points == 0 required (ops.py pads); n masked
+    in-kernel."""
     p, n = values.shape
-    t = len(types)
+    t = masses.shape[0]
     bp = min(block_points, p)
     bn = min(block_obs, max(128, 128 * ((n + 127) // 128)))
     grid = (p // bp, -(-n // bn))
@@ -254,14 +243,13 @@ def fit_error_counts(
         values = jnp.pad(values, ((0, 0), (0, n_padded - n)))
 
     return pl.pallas_call(
-        functools.partial(_fit_error_kernel, n, num_bins, tuple(types), matmul_hist),
+        functools.partial(_fit_error_kernel, n, num_bins, t, matmul_hist),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bp, bn), lambda i, j: (i, j)),
             pl.BlockSpec((bp, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((bp, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bp, num_bins + 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bp, 3 * t), lambda i, j: (i, 0)),
+            pl.BlockSpec((t, bp, num_bins), lambda i, j: (0, i, 0)),
         ],
         out_specs=pl.BlockSpec((bp, t), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((p, t), jnp.float32),
@@ -271,6 +259,5 @@ def fit_error_counts(
         values,
         vmin.reshape(p, 1).astype(jnp.float32),
         vmax.reshape(p, 1).astype(jnp.float32),
-        edges.reshape(p, num_bins + 1).astype(jnp.float32),
-        params.reshape(p, 3 * t).astype(jnp.float32),
+        masses.astype(jnp.float32),
     )

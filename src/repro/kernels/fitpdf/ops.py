@@ -90,11 +90,13 @@ def fit_errors(
 ) -> jax.Array:
     """(..., n) values + (..., T, 3) params -> (..., T) Eq.-5 errors.
 
-    Single launch: the histogram never reaches HBM, the CDF masses and the
-    Eq.-5 reduction run in the kernel epilogue while the frequency block is
-    still VMEM-resident. ``edges`` defaults to ``pe.interval_edges`` (the
-    reference formula); pass the moments kernel's emitted edges to chain
-    the two launches (see kernel.py on why edges are an input).
+    Single launch over the data: the histogram never reaches HBM, and the
+    Eq.-5 reduction runs in the kernel epilogue while the frequency block
+    is still VMEM-resident. The CDF masses at the edges are evaluated here
+    in XLA (``pe.cdf_masses``, the reference formula) and enter the kernel
+    as a small ``(T, P, L)`` input — see kernel.py on why no CDF runs in
+    the kernel. ``edges`` defaults to ``pe.interval_edges``; pass the
+    moments kernel's emitted edges to chain the two launches.
 
     ``row_indices`` (1-D, optional) is the rep-indexed gather prologue of
     the grouping-aware dispatch: ``values`` stays the *full* window while
@@ -109,6 +111,7 @@ def fit_errors(
     t = len(types)
     if edges is None:
         edges = pe.interval_edges(moments.vmin, moments.vmax, num_bins)
+    masses = pe.cdf_masses(types, params_all, edges)  # (..., T, L)
     if row_indices is not None:
         values = values.reshape(-1, values.shape[-1])[row_indices]
     shape = values.shape
@@ -118,10 +121,9 @@ def fit_errors(
     flat = _pad_rows(flat, bp)
     flo = _pad_rows(moments.vmin.reshape(-1, 1), bp)
     fhi = _pad_rows(moments.vmax.reshape(-1, 1), bp)
-    fedg = _pad_rows(edges.reshape(-1, num_bins + 1), bp)
-    fpar = _pad_rows(params_all.reshape(-1, t * 3), bp)
+    fmass = _pad_rows(masses.reshape(-1, t, num_bins), bp).transpose(1, 0, 2)
     errs = fit_error_counts(
-        flat, flo, fhi, fedg, fpar, tuple(types), num_bins,
+        flat, flo, fhi, fmass, num_bins,
         block_points=bp, block_obs=block_obs, interpret=interpret,
         matmul_hist=interpret,
     )

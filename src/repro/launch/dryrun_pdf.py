@@ -85,9 +85,7 @@ def make_window_step(variant: str, mesh, types=TYPES, num_bins=NUM_BINS,
         # The per-point fit is embarrassingly parallel (the paper's Map):
         # shard_map makes that explicit, so the partitioner cannot introduce
         # data gathers (§Perf pdf-seismic iteration 3).
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(
+        return jax.shard_map(
             core, mesh=mesh,
             in_specs=P(axes, None),
             out_specs=(P(axes), P(axes, None), P(axes), P(axes), P(axes)),
@@ -100,11 +98,9 @@ def make_window_step(variant: str, mesh, types=TYPES, num_bins=NUM_BINS,
             # quantize_keys_from_var matches the host Select path bit-exactly
             # (f64 sqrt + hi/lo int32 key pairs) at the *configured* tol —
             # this used to drop the tolerance and always group at DEFAULT_TOL.
-            from jax.experimental.shard_map import shard_map
-
             mean, var = out[3], out[4]
             keys = grp.quantize_keys_from_var(mean, var, tol=group_tol)
-            rep = shard_map(
+            rep = jax.shard_map(
                 lambda k: grp.group_device_global(k, axes).rep_for_point,
                 mesh=mesh, in_specs=P(axes), out_specs=P(axes),
             )(keys)

@@ -78,6 +78,11 @@ def main():
           f"method={spec.method.name} "
           f"mode={spec.compute.mode} fit={spec.compute.fit_backend} "
           f"select={spec.compute.select_backend}")
+    import jax
+
+    dev = jax.devices()[0]
+    print(f"[device] platform={dev.platform} kind={dev.device_kind} "
+          f"count={len(jax.devices())}")
     from repro.runtime.scheduler import assign_slices
 
     slices = session.resolve_slices(None)
@@ -175,16 +180,14 @@ def _run_once(session: PDFSession, spec: PipelineSpec) -> None:
               f"speculations={rep.speculations} "
               f"quarantined={rep.quarantined_units} "
               f"shards_lost={len(rep.shards_lost)}")
-    # cold-start visibility: with --compile-cache-dir, "new_compilations"
-    # counts persistent-cache misses (executables built fresh) — a warm
-    # relaunch of an identical spec reports new_compilations=0; without the
-    # cache it counts backend compiles outright
-    new_compilations = (rep.compile_cache_misses
-                        if spec.execution.compile_cache_dir else rep.compiles)
+    # cold-start visibility: "new_compilations" counts persistent-cache
+    # misses (executables built fresh) — a warm relaunch of an identical
+    # spec reports new_compilations=0
     print(f"[compile] traces={rep.traces} compiled={rep.compiles} "
           f"cache_hits={rep.compile_cache_hits} "
           f"cache_misses={rep.compile_cache_misses} "
-          f"new_compilations={new_compilations}")
+          f"new_compilations={rep.compile_cache_misses} "
+          f"dir={session.compile_cache_dir}")
     if window_durations:
         med = sorted(window_durations)[len(window_durations) // 2]
         print(f"[total] wall={wall:.3f}s windows={rep.windows} "

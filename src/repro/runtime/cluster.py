@@ -27,9 +27,10 @@ Three seams live here:
   (``process_id >= num_processes``) adds itself via ``plan_redeal``'s
   ``joined`` parameter: it duplicates at worst (identical bytes), and when
   every original shard died it completes the run alone.
-* **Cold start**: ``enable_compilation_cache`` keys the persistent XLA
-  compilation cache under ``<compile_cache_dir>/<spec_hash>``, so a
-  re-launched identical spec serves every executable from disk;
+* **Cold start**: ``enable_compilation_cache`` points the persistent XLA
+  compilation cache at ``JAX_COMPILATION_CACHE_DIR``, else at
+  ``ExecSpec.compile_cache_dir``, else at a fixed ``.jax_cache/`` in the
+  checkout, so a re-launched spec serves every executable from disk;
   ``compile_counters`` snapshots the process-wide trace/compile/cache
   event counts (``jax.monitoring``) that ``SessionReport`` exposes so
   "zero new compilations" is assertable. A corrupt cache entry is a warned
@@ -124,29 +125,40 @@ def counters_delta(baseline: dict[str, int]) -> dict[str, int]:
 # -- persistent compilation cache ----------------------------------------------
 
 
-def enable_compilation_cache(base_dir: str | Path, spec_hash: str) -> Path:
-    """Point JAX's persistent compilation cache at ``<base_dir>/<spec_hash>``
-    — keyed next to the spec hash so the cache directory carries the same
-    provenance as every other artifact, and a spec change never pollutes or
-    reuses another spec's entries. Thresholds are dropped to cache
-    everything (the pipeline's executables are small and re-launch cost is
-    the point). Safe to call repeatedly; switching directories resets JAX's
-    in-memory cache handle."""
+# The cache directory when neither JAX_COMPILATION_CACHE_DIR nor
+# ExecSpec.compile_cache_dir names one: a fixed path inside the checkout
+# (gitignored). The path is part of what a later launch must find again, so
+# it is never built from a temporary name, a pid or the time.
+DEFAULT_COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compilation_cache(configured: str | Path | None = None) -> Path:
+    """Turn JAX's persistent compilation cache on, and return its directory:
+    ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it from the
+    environment, so no directory is set here), else ``configured``
+    (``ExecSpec.compile_cache_dir``) as the directory itself, else
+    ``DEFAULT_COMPILE_CACHE_DIR``.
+
+    One directory serves every spec: XLA keys each entry by the program,
+    device and flags, so identical kernels of two specs share an entry.
+    Thresholds are dropped to cache everything (the pipeline's executables
+    are small, and re-launch cost is the point) — which also makes a
+    persistent-cache miss mean exactly "built fresh". Safe to call
+    repeatedly; switching directories resets JAX's in-memory cache handle."""
     import jax
 
-    path = Path(base_dir) / spec_hash
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    path = Path(env or configured or DEFAULT_COMPILE_CACHE_DIR)
     path.mkdir(parents=True, exist_ok=True)
-    previous = getattr(jax.config, "jax_compilation_cache_dir", None)
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    if previous and previous != str(path):
-        try:
-            from jax._src import compilation_cache
+    if not env:
+        previous = jax.config.jax_compilation_cache_dir
+        jax.config.update("jax_compilation_cache_dir", str(path))
+        if previous and previous != str(path):
+            from jax.experimental.compilation_cache import compilation_cache
 
             compilation_cache.reset_cache()
-        except (ImportError, AttributeError):  # cache handle resets lazily
-            pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     return path
 
 

@@ -5,8 +5,15 @@
 import os
 from datetime import timedelta
 
+import jax
 import numpy as np
 import pytest
+
+# The persistent compilation cache pays off only across processes, and the
+# cold-start tests (test_distributed.py) relaunch subprocesses to see it.
+# In-process, the test workers would only race on partly written entries
+# of one cache directory.
+jax.config.update("jax_enable_compilation_cache", False)
 
 try:
     from hypothesis import settings as _hyp_settings

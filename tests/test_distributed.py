@@ -49,6 +49,7 @@ def _free_port() -> int:
 
 def _worker_env() -> dict:
     env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env["PYTHONPATH"] = str(REPO / "src")
     env["JAX_PLATFORMS"] = "cpu"
     env["JAX_NUM_CPU_DEVICES"] = "1"
@@ -56,11 +57,12 @@ def _worker_env() -> dict:
     return env
 
 
-def _run_serial(out_dir, extra=()) -> str:
+def _run_serial(out_dir, extra=(), env=None) -> str:
     p = subprocess.run(
         [sys.executable, "-m", "repro.launch.run_pdf", *SPEC_FLAGS,
          "--out-dir", str(out_dir), *extra],
-        env=_worker_env(), capture_output=True, text=True, timeout=600)
+        env={**_worker_env(), **(env or {})}, capture_output=True, text=True,
+        timeout=600)
     assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr}"
     return p.stdout + p.stderr
 
@@ -170,10 +172,11 @@ def test_second_launch_reports_zero_new_compilations(warm_cache):
     assert _new_compilations(log1) > 0  # the first launch really compiled
     assert _new_compilations(log2) == 0
     assert re.search(r"cache_hits=[1-9]", log2)
-    # the cache is keyed under the spec hash, next to every other artifact
-    spec_hash = re.search(r"hash=([0-9a-f]{16})", log2).group(1)
-    assert (cache / spec_hash).is_dir()
-    assert any((cache / spec_hash).iterdir())
+    # the entries sit in the given directory itself (XLA keys each by the
+    # program), with no per-spec subdirectory
+    assert any(f.is_file() for f in cache.iterdir())
+    assert not any(f.is_dir() for f in cache.iterdir())
+    assert f"dir={cache}" in log2
     # and the warm run's persisted windows are the cold run's, bitwise
     verify_outputs(base / "run1", base / "run2")
 
@@ -194,3 +197,19 @@ def test_corrupt_cache_entry_is_warned_miss_not_crash(warm_cache):
     assert ("compilation cache" in log3 and "rror" in log3) \
         or _new_compilations(log3) > 0, log3
     verify_outputs(base / "run1", base / "run3")
+
+
+def test_env_cache_dir_wins_over_spec(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set in the environment, the program
+    uses exactly that directory: the spec's --compile-cache-dir gets
+    nothing, and a relaunch hits the environment's entries."""
+    env_dir, spec_dir = tmp_path / "env-cache", tmp_path / "spec-cache"
+    extra = ["--compile-cache-dir", str(spec_dir)]
+    env = {"JAX_COMPILATION_CACHE_DIR": str(env_dir)}
+    log1 = _run_serial(tmp_path / "run1", extra, env=env)
+    log2 = _run_serial(tmp_path / "run2", extra, env=env)
+    assert f"dir={env_dir}" in log1
+    assert _new_compilations(log1) > 0
+    assert _new_compilations(log2) == 0
+    assert any(f.is_file() for f in env_dir.iterdir())
+    assert not spec_dir.exists()
