@@ -1,0 +1,8 @@
+"""1 - (union of device-operation intervals / traced window), in percent,
+averaged over the chips the cell uses (``bench/trace.py``)."""
+
+
+def read(ctx):
+    if ctx.trace is None or ctx.trace["window_s"] <= 0 or ctx.trace["busy_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - ctx.trace["busy_s"] / ctx.trace["window_s"])

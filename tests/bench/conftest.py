@@ -1,0 +1,7 @@
+import sys
+from pathlib import Path
+
+# the benchmark is imported as the package ``bench`` from the repository root
+ROOT = str(Path(__file__).resolve().parents[2])
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
