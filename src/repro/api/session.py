@@ -33,6 +33,7 @@ from repro.core import regions
 from repro.core.executor import ExecutorReport, SliceResult, StagedExecutor
 from repro.runtime import elastic
 from repro.runtime.faults import FaultInjector, FaultPlan, ShardLostError
+from repro.runtime.monitor import SpanRecorder, merge_totals
 from repro.runtime.scheduler import assign_slices
 
 
@@ -82,6 +83,11 @@ class SessionReport:
     # executors' StepMonitors, merged across shards. The serve layer's stats
     # endpoint reuses the same monitors/estimator verbatim.
     stage_percentiles: dict[str, dict[str, float]] = field(default_factory=dict)
+    # Span totals {name: (seconds, count)} and work counters {name: count},
+    # summed over the executor reports, plus the session's own spans
+    # (``pdf.session.open``, ``pdf.executor.build``).
+    spans: dict = field(default_factory=dict)
+    counters: dict = field(default_factory=dict)
 
     @property
     def load_hidden_seconds(self) -> float:
@@ -108,56 +114,58 @@ class PDFSession:
                  fault_injector: FaultInjector | None = None):
         if not isinstance(spec, PipelineSpec):
             raise TypeError(f"spec must be a PipelineSpec, got {type(spec).__name__}")
-        self.spec = spec
-        self.source = data_source if data_source is not None else build_source(spec.source)
-        self._tree = tree
-        self._executors: dict[int, StagedExecutor] = {}
-        self._reports: dict[int, list[ExecutorReport]] = {}
-        self._slices_done = 0
-        # Chaos layer (DESIGN.md §14): an explicit injector wins; otherwise
-        # ExecSpec.fault_plan (the --fault-plan JSON file) builds one. Each
-        # shard's executor reads through its own injector-wrapped source,
-        # so shard-targeted rules (shard_death) see the right identity.
-        self.injector = fault_injector
-        if self.injector is None and spec.execution.fault_plan:
-            self.injector = FaultInjector(
-                FaultPlan.load(spec.execution.fault_plan))
-        self.shards_lost: tuple[int, ...] = ()
-        # Hashed once: the spec is frozen, and for kind='file' hashing reads
-        # + digests the on-disk manifest — per-slice cache lookups must not
-        # repeat that (and a manifest swapped mid-run must not split the
-        # session across two hashes).
-        self._spec_hash = spec.content_hash()
-        # Cold-start elimination (DESIGN.md §17): the persistent XLA
-        # compilation cache (runtime.cluster.enable_compilation_cache picks
-        # the directory), so a re-launched spec serves every executable from
-        # disk. Enabled before any executor compiles; the counter baseline
-        # makes report() deltas session-scoped.
-        from repro.runtime import cluster as _cluster
+        self.spans = SpanRecorder()  # the session's own spans (report())
+        with self.spans.span("pdf.session.open", slice=-1, line=-1):
+            self.spec = spec
+            self.source = data_source if data_source is not None else build_source(spec.source)
+            self._tree = tree
+            self._executors: dict[int, StagedExecutor] = {}
+            self._reports: dict[int, list[ExecutorReport]] = {}
+            self._slices_done = 0
+            # Chaos layer (DESIGN.md §14): an explicit injector wins; otherwise
+            # ExecSpec.fault_plan (the --fault-plan JSON file) builds one. Each
+            # shard's executor reads through its own injector-wrapped source,
+            # so shard-targeted rules (shard_death) see the right identity.
+            self.injector = fault_injector
+            if self.injector is None and spec.execution.fault_plan:
+                self.injector = FaultInjector(
+                    FaultPlan.load(spec.execution.fault_plan))
+            self.shards_lost: tuple[int, ...] = ()
+            # Hashed once: the spec is frozen, and for kind='file' hashing reads
+            # + digests the on-disk manifest — per-slice cache lookups must not
+            # repeat that (and a manifest swapped mid-run must not split the
+            # session across two hashes).
+            self._spec_hash = spec.content_hash()
+            # Cold-start elimination (DESIGN.md §17): the persistent XLA
+            # compilation cache (runtime.cluster.enable_compilation_cache picks
+            # the directory), so a re-launched spec serves every executable from
+            # disk. Enabled before any executor compiles; the counter baseline
+            # makes report() deltas session-scoped.
+            from repro.runtime import cluster as _cluster
 
-        self.compile_cache_dir = _cluster.enable_compilation_cache(
-            spec.execution.compile_cache_dir)
-        self._compile_baseline = _cluster.compile_counters()
-        self.cache = (ResultCache(spec.execution.cache_dir,
-                                  max_bytes=spec.execution.cache_max_bytes,
-                                  injector=self.injector)
-                      if spec.execution.cache_dir else None)
-        self.cache_hits = 0
-        self.cache_misses = 0
-        self.cache_adopted = 0
-        self.slices_merged = 0
-        self._manifest: dict | None = None  # file-source manifest, read once
-        self._lineage: tuple[str, ...] | None = None  # archived-version hashes
-        if self.cache is not None and spec.source.kind == "external":
-            # Same honesty gap as resume: the hash covers the pipeline
-            # knobs but cannot capture an external source's data identity,
-            # so a cache entry could be served to a run over different data.
-            warnings.warn(
-                "result cache with an external data source: the spec hash "
-                "keys the pipeline knobs only, not the dataset's identity — "
-                "make sure cache_dir belongs to this source (or export the "
-                "data with file_source.export_cube and use kind='file')",
-                stacklevel=2)
+            self.compile_cache_dir = _cluster.enable_compilation_cache(
+                spec.execution.compile_cache_dir)
+            self._compile_baseline = _cluster.compile_counters()
+            self.cache = (ResultCache(spec.execution.cache_dir,
+                                      max_bytes=spec.execution.cache_max_bytes,
+                                      injector=self.injector)
+                          if spec.execution.cache_dir else None)
+            self.cache_hits = 0
+            self.cache_misses = 0
+            self.cache_adopted = 0
+            self.slices_merged = 0
+            self._manifest: dict | None = None  # file-source manifest, read once
+            self._lineage: tuple[str, ...] | None = None  # archived-version hashes
+            if self.cache is not None and spec.source.kind == "external":
+                # Same honesty gap as resume: the hash covers the pipeline
+                # knobs but cannot capture an external source's data identity,
+                # so a cache entry could be served to a run over different data.
+                warnings.warn(
+                    "result cache with an external data source: the spec hash "
+                    "keys the pipeline knobs only, not the dataset's identity — "
+                    "make sure cache_dir belongs to this source (or export the "
+                    "data with file_source.export_cube and use kind='file')",
+                    stacklevel=2)
 
     # -- components ------------------------------------------------------------
 
@@ -201,38 +209,42 @@ class PDFSession:
         (read faults, shard death) and its persist stage gets the injector's
         write hook."""
         if shard not in self._executors:
-            source = self.source
-            if self.injector is not None:
-                source = self.injector.wrap_source(source, shard=shard)
-            sharding = None
-            if self.spec.execution.placement.shard_devices is not None:
-                from repro.runtime import cluster
-
-                # the per-shard device placement seam: stage this shard's
-                # windows onto its pinned local device (bitwise-invariant —
-                # same executable, same inputs, different queue)
-                sharding = cluster.device_placement(
-                    self.spec.execution.placement, shard)
-            recorder = None
-            if (self.spec.stream.persist_stats
-                    and self.spec.execution.out_dir is not None):
-                from repro.streaming.stats import StatsRecorder
-
-                recorder = StatsRecorder(self.spec.execution.out_dir,
-                                         self.spec.compute.num_bins,
-                                         spec_hash=self.spec_hash)
-            self._executors[shard] = StagedExecutor(
-                self.spec.pdf_config(),
-                source,
-                tree=self.tree,
-                out_dir=self.spec.execution.out_dir,
-                sharding=sharding,
-                exec_config=self.spec.exec_config(),
-                spec_hash=self.spec_hash,
-                injector=self.injector,
-                stats_recorder=recorder,
-            )
+            with self.spans.span("pdf.executor.build", slice=-1, line=-1):
+                self._executors[shard] = self._build_executor(shard)
         return self._executors[shard]
+
+    def _build_executor(self, shard: int) -> StagedExecutor:
+        source = self.source
+        if self.injector is not None:
+            source = self.injector.wrap_source(source, shard=shard)
+        sharding = None
+        if self.spec.execution.placement.shard_devices is not None:
+            from repro.runtime import cluster
+
+            # the per-shard device placement seam: stage this shard's
+            # windows onto its pinned local device (bitwise-invariant —
+            # same executable, same inputs, different queue)
+            sharding = cluster.device_placement(
+                self.spec.execution.placement, shard)
+        recorder = None
+        if (self.spec.stream.persist_stats
+                and self.spec.execution.out_dir is not None):
+            from repro.streaming.stats import StatsRecorder
+
+            recorder = StatsRecorder(self.spec.execution.out_dir,
+                                     self.spec.compute.num_bins,
+                                     spec_hash=self.spec_hash)
+        return StagedExecutor(
+            self.spec.pdf_config(),
+            source,
+            tree=self.tree,
+            out_dir=self.spec.execution.out_dir,
+            sharding=sharding,
+            exec_config=self.spec.exec_config(),
+            spec_hash=self.spec_hash,
+            injector=self.injector,
+            stats_recorder=recorder,
+        )
 
     # -- execution -------------------------------------------------------------
 
@@ -558,8 +570,10 @@ class PDFSession:
         """Aggregate per-stage totals over everything run so far."""
         totals = dict(wall=0.0, load=0.0, wait=0.0, compute=0.0, persist=0.0)
         windows = retries = speculations = quarantined = 0
+        spans, counters = self.spans.snapshot()
         for reps in self._reports.values():
             for r in reps:
+                merge_totals(spans, counters, r.spans, r.counters)
                 totals["wall"] += r.wall_seconds
                 totals["load"] += r.load_seconds
                 totals["wait"] += r.wait_seconds
@@ -595,4 +609,6 @@ class PDFSession:
             persist_seconds=totals["persist"],
             shard_reports={k: list(v) for k, v in self._reports.items()},
             stage_percentiles=self.stage_percentiles(),
+            spans=spans,
+            counters=counters,
         )

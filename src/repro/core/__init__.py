@@ -4,17 +4,28 @@
 # the windowed pipeline (Algorithms 1-2), run by a staged executor that
 # overlaps load / compute / persist (executor.py) — all as fused JAX
 # computations.
-from repro.core import distributions, fitting, grouping, ml_predict, pdf_error
-from repro.core import executor, pipeline, regions, reuse, sampling
-from repro.core.distributions import TYPES_4, TYPES_10, Moments, moments_from_values
-from repro.core.fitting import FitResult, compute_pdf_and_error, compute_pdf_with_predicted_type
-from repro.core.executor import (
-    ExecutorConfig,
-    ExecutorReport,
-    StagedExecutor,
-)
-from repro.core.pipeline import PDFComputer, PDFConfig, SliceResult
-from repro.core.regions import CubeGeometry, Plan, Window, WorkUnit, build_plan, iter_windows
+#
+# Submodules and the names below are imported on first use (PEP 562), so
+# importing one submodule (``repro.core.regions``, which ``repro.data``
+# needs) does not import the executor. An eager package import made the
+# package and its submodules a cycle (executor -> repro.core -> executor),
+# and two threads importing different ends of it could be handed a partly
+# initialized module.
+import importlib
+
+_SUBMODULES = ("distributions", "executor", "fitting", "grouping", "ml_predict",
+               "pdf_error", "pipeline", "regions", "reuse", "sampling")
+_NAMES = {
+    "TYPES_4": "distributions", "TYPES_10": "distributions",
+    "Moments": "distributions", "moments_from_values": "distributions",
+    "FitResult": "fitting", "compute_pdf_and_error": "fitting",
+    "compute_pdf_with_predicted_type": "fitting",
+    "ExecutorConfig": "executor", "ExecutorReport": "executor",
+    "StagedExecutor": "executor",
+    "PDFComputer": "pipeline", "PDFConfig": "pipeline", "SliceResult": "pipeline",
+    "CubeGeometry": "regions", "Plan": "regions", "Window": "regions",
+    "WorkUnit": "regions", "build_plan": "regions", "iter_windows": "regions",
+}
 
 __all__ = [
     "TYPES_4", "TYPES_10", "Moments", "moments_from_values",
@@ -25,3 +36,11 @@ __all__ = [
     "distributions", "executor", "fitting", "grouping", "ml_predict",
     "pdf_error", "pipeline", "regions", "reuse", "sampling",
 ]
+
+
+def __getattr__(name):
+    if name in _SUBMODULES:
+        return importlib.import_module(f"{__name__}.{name}")
+    if name in _NAMES:
+        return getattr(importlib.import_module(f"{__name__}.{_NAMES[name]}"), name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -26,6 +26,19 @@ stage), so straggler flagging and stage medians come for free; the
 (``wait_seconds`` is the only part of the load the device actually blocked
 on).
 
+Each step is a named span (``runtime.monitor.SpanRecorder``, one per
+executor) carrying the window's ``slice`` and ``line``: ``pdf.load.read``
+and ``pdf.load.h2d`` on the prefetch thread, ``pdf.persist.write`` on the
+writer thread, and on the main thread ``pdf.slice.open``,
+``pdf.load.wait``, ``pdf.moments``, ``pdf.select``, ``pdf.fit.launch``,
+``pdf.fit.wait``, ``pdf.handoff`` and ``pdf.slice.drain``. Main-thread spans
+never nest (with prefetch off, the load spans run on the main thread,
+inside ``pdf.load.wait``). The heartbeats and the report's stage totals take their times
+from the spans' own clock reads; ``ExecutorReport.spans`` and ``counters``
+carry the run's span totals and work counts (``windows``, ``points``,
+``fit_rows``, ``fit_rows_padded``, ``groups``, ``bytes_read``,
+``bytes_h2d``).
+
 ``PDFComputer`` (pipeline.py) is a thin facade over this executor; the
 multi-slice entry point is ``run`` on a ``regions.Plan``, which
 ``runtime.scheduler`` uses for per-node slice assignment.
@@ -57,7 +70,7 @@ from repro.core import regions
 from repro.core.reuse import ReuseCache
 from repro.data.loader import PrefetchError, WindowPrefetcher
 from repro.runtime.faults import ShardLostError, is_transient
-from repro.runtime.monitor import StepMonitor, StragglerPolicy
+from repro.runtime.monitor import SpanRecorder, StepMonitor, StragglerPolicy
 
 METHODS = (
     "baseline", "grouping", "reuse", "ml", "grouping_ml", "reuse_ml",
@@ -324,6 +337,10 @@ class ExecutorReport:
     speculations: int = 0
     speculation_wins: int = 0
     quarantined: int = 0
+    # The run's span totals {name: (seconds, count)} and work counters
+    # {name: count}, from the executor's SpanRecorder.
+    spans: dict = field(default_factory=dict)
+    counters: dict = field(default_factory=dict)
 
     @property
     def load_hidden_seconds(self) -> float:
@@ -479,6 +496,19 @@ class _FailedUnit(NamedTuple):
     attempts: int
 
 
+def _ids(w: regions.Window) -> dict:
+    """A window's span arguments: its slice and first line, which link the
+    window's spans across the prefetch, main and writer threads."""
+    return {"slice": w.slice_i, "line": w.line_start}
+
+
+def _moments_np(moments) -> tuple:
+    """Host (mean, std, skew, kurt) of a window's device moments."""
+    return (np.asarray(moments[0]),
+            np.sqrt(np.maximum(np.asarray(moments[1]), 0)),
+            np.asarray(moments[2]), np.asarray(moments[3]))
+
+
 def _errstr(e: BaseException) -> str:
     return f"{type(e).__name__}: {e}"
 
@@ -524,9 +554,11 @@ class PersistStage:
                  monitor: StepMonitor | None = None,
                  spec_hash: str | None = None,
                  injector=None,
-                 total_lines: int | None = None):
+                 total_lines: int | None = None,
+                 spans: SpanRecorder | None = None):
         self.out_dir = Path(out_dir) if out_dir else None
         self.monitor = monitor
+        self.spans = spans if spans is not None else SpanRecorder()
         self.spec_hash = spec_hash  # stamped into every .npz + watermark
         # Lines per slice, when the caller knows it: lets the watermark
         # carry an explicit ``complete`` stamp (the cluster redeal scan's
@@ -573,33 +605,33 @@ class PersistStage:
 
     def _write(self, slice_i: int, w: regions.Window, arrays: dict[str, np.ndarray]):
         uid = f"persist:s{slice_i}/l{w.line_start:05d}"
-        t0 = time.perf_counter()
-        if self.monitor is not None:
-            self.monitor.start(uid, now=t0)
-        try:
-            # Transient write failures (an NFS hiccup mid-savez, or the
-            # injector's persist_error) get two quiet re-attempts — a
-            # partially-written .npz is simply overwritten, and the
-            # watermark only advances after a successful write.
-            for attempt in range(3):
-                try:
-                    if self.injector is not None:
-                        self.injector.on_persist(slice_i, w.line_start)
-                    self._write_once(slice_i, w, arrays)
-                    break
-                except OSError:
-                    if attempt == 2:
-                        raise
-                    self.retries += 1
-                    time.sleep(0.01 * (attempt + 1))
-        except BaseException:
+        with self.spans.span("pdf.persist.write", slice=slice_i,
+                             line=w.line_start) as sp:
             if self.monitor is not None:
-                self.monitor.abandon(uid)
-            raise
-        t1 = time.perf_counter()
+                self.monitor.start(uid, now=sp.start)
+            try:
+                # Transient write failures (an NFS hiccup mid-savez, or the
+                # injector's persist_error) get two quiet re-attempts — a
+                # partially-written .npz is simply overwritten, and the
+                # watermark only advances after a successful write.
+                for attempt in range(3):
+                    try:
+                        if self.injector is not None:
+                            self.injector.on_persist(slice_i, w.line_start)
+                        self._write_once(slice_i, w, arrays)
+                        break
+                    except OSError:
+                        if attempt == 2:
+                            raise
+                        self.retries += 1
+                        time.sleep(0.01 * (attempt + 1))
+            except BaseException:
+                if self.monitor is not None:
+                    self.monitor.abandon(uid)
+                raise
         if self.monitor is not None:
-            self.monitor.finish(uid, now=t1)
-        self.seconds += t1 - t0
+            self.monitor.finish(uid, now=sp.end)
+        self.seconds += sp.seconds
         self.writes += 1
 
     def _write_once(self, slice_i: int, w: regions.Window,
@@ -742,6 +774,7 @@ class StagedExecutor:
         # sufficient statistics can be persisted without a second read.
         self.stats_recorder = stats_recorder
         self.cache = ReuseCache()
+        self.spans = SpanRecorder()
         if ("ml" in config.method or config.method == "sampling") and tree is None:
             raise ValueError(f"method {config.method!r} requires a decision tree")
         if config.select_backend == "device":
@@ -794,8 +827,11 @@ class StagedExecutor:
         # Host -> the shard's own device in one copy: staging through
         # jnp.asarray first would land every window on the default device.
         if self.sharding is not None:
-            return jax.device_put(np.asarray(values, np.float32), self.sharding)
-        return jnp.asarray(values, dtype=jnp.float32)
+            staged = jax.device_put(np.asarray(values, np.float32), self.sharding)
+        else:
+            staged = jnp.asarray(values, dtype=jnp.float32)
+        self.spans.count("bytes_h2d", staged.nbytes)
+        return staged
 
     def _load_unit(self, unit: regions.WorkUnit,
                    uid: str | None = None) -> _StagedWindow:
@@ -807,17 +843,19 @@ class StagedExecutor:
         cannot poison the straggler median."""
         mon = self.monitors["load"]
         uid = uid or unit.unit_id
-        t0 = time.perf_counter()
-        mon.start(uid, now=t0)
+        ids = _ids(unit.window)
         try:
-            raw = self.data.load_window(unit.window)  # (P, n_obs)
-            values = self._stage(raw)
+            with self.spans.span("pdf.load.read", **ids) as read:
+                mon.start(uid, now=read.start)
+                raw = self.data.load_window(unit.window)  # (P, n_obs)
+            self.spans.count("bytes_read", raw.nbytes)
+            with self.spans.span("pdf.load.h2d", start=read.end, **ids) as h2d:
+                values = self._stage(raw)
         except BaseException:
             mon.abandon(uid)
             raise
-        t1 = time.perf_counter()
-        mon.finish(uid, now=t1)
-        return _StagedWindow(unit, values, t1 - t0)
+        mon.finish(uid, now=h2d.end)
+        return _StagedWindow(unit, values, h2d.end - read.start)
 
     # -- fault tolerance: retry, speculation, quarantine (DESIGN.md §14) -------
 
@@ -914,13 +952,20 @@ class StagedExecutor:
 
     # -- compute stage: ComputePDF&Error dispatch per method -------------------
 
-    def _fit(self, values: jax.Array, moments: dists.Moments):
-        """Fit every row of ``values``; returns np arrays (type, params, err)."""
+    def _fit_launch(self, values: jax.Array, moments: dists.Moments):
+        """Dispatch the fit of every row of ``values`` (the tree's predict
+        folded in for the ml methods); returns device (type, params, err)."""
         if self._tree_arrays is not None and "ml" in self.config.method:
-            t, p, e = self._fit_pred(values, moments, self._tree_arrays)
-        else:
-            t, p, e = self._fit_all(values, moments)
-        return np.asarray(t), np.asarray(p), np.asarray(e)
+            return self._fit_pred(values, moments, self._tree_arrays)
+        return self._fit_all(values, moments)
+
+    def _fit(self, values: jax.Array, moments: dists.Moments, ids: dict,
+             start: float | None = None):
+        """Fit every row of ``values``; returns np arrays (type, params, err)."""
+        with self.spans.span("pdf.fit.launch", start=start, **ids) as launch:
+            fitted = self._fit_launch(values, moments)
+        with self.spans.span("pdf.fit.wait", start=launch.end, **ids):
+            return tuple(np.asarray(x) for x in fitted)
 
     def _quantized_keys(self, moments: dists.Moments) -> np.ndarray:
         """Host-side (mu, sigma) quantization into a cached (P, 2) buffer
@@ -949,7 +994,8 @@ class StagedExecutor:
     def _select_and_fit(self, values: jax.Array, moments: dists.Moments,
                         window: regions.Window,
                         sample_idx: np.ndarray | None = None,
-                        total_points: int | None = None):
+                        total_points: int | None = None,
+                        start: float | None = None):
         """The Select step (§5.1/5.2): returns per-point results + bookkeeping.
 
         Dispatches on ``config.select_backend``: 'host' dedups via np.unique
@@ -958,27 +1004,36 @@ class StagedExecutor:
         hi/lo splits of the host int64 keys, and fits are row-deterministic).
         ``window``/``sample_idx``/``total_points`` only feed the sampling
         method (for every other method ``values`` covers the whole window).
+        ``start``: a clock read taken just before (the end of the moments
+        span), at which the first span here begins; the spans then tile the
+        step (``pdf.select``, ``pdf.fit.launch``, ``pdf.fit.wait``).
         """
         method = self.config.method
         num_points = values.shape[0]
+        ids = _ids(window)
         if method == "sampling":
-            return self._sample_classify(
-                moments, window, total_points or num_points, sample_idx
-            )
+            with self.spans.span("pdf.select", start=start, **ids):
+                return self._sample_classify(
+                    moments, window, total_points or num_points, sample_idx
+                )
         if method in ("baseline", "ml"):
-            t, p, e = self._fit(values, moments)
+            t, p, e = self._fit(values, moments, ids, start)
+            self.spans.count("fit_rows", num_points)
+            self.spans.count("fit_rows_padded", num_points)
             return t, p, e, num_points, 0
         if self._sel_fns is not None:
-            return self._select_device(values, moments)
+            return self._select_device(values, moments, ids, start)
 
         # grouping / reuse variants: dedup on host, fit representatives only.
-        keys = self._quantized_keys(moments)
-        groups = grp.group_host(keys)
-        rep_t, rep_p, rep_e, fitted, cache_hits = self._fit_representatives(
-            values, moments, keys[groups.rep_indices], groups.rep_indices
+        with self.spans.span("pdf.select", start=start, **ids) as sel:
+            keys = self._quantized_keys(moments)
+            groups = grp.group_host(keys)
+            rep_keys = keys[groups.rep_indices]
+        self.spans.count("groups", groups.num_groups)
+        return self._fit_representatives(
+            values, moments, rep_keys, groups.rep_indices, groups.inverse,
+            ids, sel.end,
         )
-        inv = groups.inverse
-        return rep_t[inv], rep_p[inv], rep_e[inv], fitted, cache_hits
 
     def _fit_representatives(
         self,
@@ -986,53 +1041,71 @@ class StagedExecutor:
         moments: dists.Moments,
         rep_keys: np.ndarray,
         rep_rows: np.ndarray,
+        inverse,
+        ids: dict,
+        start: float,
     ):
         """Fit one row per group — the Select core shared by both backends.
 
         ``rep_keys`` (G, 2) int64 is each group's cache identity; ``rep_rows``
-        (G,) the representatives' window row indices. Consults the reuse
-        cache when the method carries one, fits the misses via the padded
-        re-dispatch, and returns per-*group* results
-        ``(rep_t, rep_p, rep_e, fitted, cache_hits)`` — the caller scatters
-        them per point with its own inverse map."""
+        (G,) the representatives' window row indices; ``inverse`` (P,) each
+        point's group. Consults the reuse cache when the method carries one,
+        fits the misses via the padded re-dispatch, and returns per-*point*
+        results ``(t, p, e, fitted, cache_hits)``. Its ``pdf.fit.launch``
+        span (the cache lookup, the gather and the fit's dispatch) starts at
+        ``start``, the end of the Select span before it, and ``pdf.fit.wait``
+        (the copy to the host and the scatter per point) follows on."""
         method = self.config.method
         g = len(rep_rows)
-        if method.startswith("reuse"):
-            hit, cached = self.cache.lookup_window(rep_keys)
-            cache_hits = int(hit.sum())
-            todo = rep_rows[~hit]
-        else:
-            hit = np.zeros((g,), dtype=bool)
-            cached = np.zeros((g, 5))
-            todo = rep_rows
-            cache_hits = 0
-
-        rep_t = np.zeros((g,), dtype=np.int32)
-        rep_p = np.zeros((g, 3), dtype=np.float32)
-        rep_e = np.zeros((g,), dtype=np.float32)
-        rep_t[hit] = cached[hit, 0].astype(np.int32)
-        rep_p[hit] = cached[hit, 1:4]
-        rep_e[hit] = cached[hit, 4]
-
-        if len(todo):
-            padded = grp.pad_representatives(todo, self.config.rep_bucket)
-            # Single device gather for values + all moment fields (the old
-            # per-field np.asarray round-trips cost ~7 transfers per window).
-            sub_vals, sub_mom = self._gather(values, moments, jnp.asarray(padded))
-            t, p, e = self._fit(sub_vals, sub_mom)  # dispatches ML per method
-            t, p, e = t[: len(todo)], p[: len(todo)], e[: len(todo)]
-            rep_t[~hit], rep_p[~hit], rep_e[~hit] = t, p, e
+        launched = sub_vals = sub_mom = None
+        with self.spans.span("pdf.fit.launch", start=start, **ids) as launch:
             if method.startswith("reuse"):
-                self.cache.insert_window(
-                    rep_keys[~hit],
-                    np.concatenate(
-                        [t[:, None], p, e[:, None]], axis=-1
-                    ).astype(np.float64),
-                )
+                hit, cached = self.cache.lookup_window(rep_keys)
+                cache_hits = int(hit.sum())
+                todo = rep_rows[~hit]
+            else:
+                hit = np.zeros((g,), dtype=bool)
+                cached = np.zeros((g, 5))
+                todo = rep_rows
+                cache_hits = 0
 
-        return rep_t, rep_p, rep_e, len(todo), cache_hits
+            rep_t = np.zeros((g,), dtype=np.int32)
+            rep_p = np.zeros((g, 3), dtype=np.float32)
+            rep_e = np.zeros((g,), dtype=np.float32)
+            rep_t[hit] = cached[hit, 0].astype(np.int32)
+            rep_p[hit] = cached[hit, 1:4]
+            rep_e[hit] = cached[hit, 4]
 
-    def _select_device(self, values: jax.Array, moments: dists.Moments):
+            if len(todo):
+                padded = grp.pad_representatives(todo, self.config.rep_bucket)
+                # Single device gather for values + all moment fields (the
+                # old per-field np.asarray round-trips cost ~7 transfers per
+                # window).
+                sub_vals, sub_mom = self._gather(values, moments,
+                                                 jnp.asarray(padded))
+                launched = self._fit_launch(sub_vals, sub_mom)  # ML per method
+                self.spans.count("fit_rows", len(todo))
+                self.spans.count("fit_rows_padded", len(padded))
+
+        with self.spans.span("pdf.fit.wait", start=launch.end, **ids):
+            if launched is not None:
+                t, p, e = (np.asarray(x)[: len(todo)] for x in launched)
+                rep_t[~hit], rep_p[~hit], rep_e[~hit] = t, p, e
+                if method.startswith("reuse"):
+                    self.cache.insert_window(
+                        rep_keys[~hit],
+                        np.concatenate(
+                            [t[:, None], p, e[:, None]], axis=-1
+                        ).astype(np.float64),
+                    )
+            inv = np.asarray(inverse)
+            out = rep_t[inv], rep_p[inv], rep_e[inv], len(todo), cache_hits
+            # the fit's device buffers are released here, inside the span
+            del launched, sub_vals, sub_mom
+        return out
+
+    def _select_device(self, values: jax.Array, moments: dists.Moments,
+                       ids: dict, start: float | None = None):
         """Device-side Select (select_backend='device'): the grouping hot
         path never leaves the accelerator. ``probe`` quantizes + sorts on
         device; the only D2H is the scalar group count (needed to pick the
@@ -1048,35 +1121,42 @@ class StagedExecutor:
         to the host path."""
         method = self.config.method
         fns = self._sel_fns
-        num_g, rep_for_point, is_rep = fns.probe(moments)
-        g = int(num_g)  # the one sync of the device Select path
-        padded_g = grp.padded_size(g, self.config.rep_bucket)
+        grouping = method.startswith("grouping")
+        with self.spans.span("pdf.select", start=start, **ids) as sel:
+            num_g, rep_for_point, is_rep = fns.probe(moments)
+            g = int(num_g)  # the one sync of the device Select path
+            padded_g = grp.padded_size(g, self.config.rep_bucket)
+            if not grouping:
+                # reuse / reuse_ml: device dedup + host cache — only the
+                # compacted (G,) rep keys/rows and the (P,) slot map come
+                # down, then the representative-fit core runs exactly as on
+                # the host path.
+                gather_idx, rep_keys4, point_slot = fns.compact(
+                    moments, rep_for_point, is_rep, padded_g
+                )
+                rep_rows = np.asarray(gather_idx)[:g].astype(np.int64)
+                rep_keys = grp.keys_to_int64(np.asarray(rep_keys4)[:g])  # (G, 2)
+        self.spans.count("groups", g)
+        if not grouping:
+            return self._fit_representatives(
+                values, moments, rep_keys, rep_rows, point_slot, ids, sel.end
+            )
 
-        if method.startswith("grouping"):
+        with self.spans.span("pdf.fit.launch", start=sel.end, **ids) as launch:
             if self._tree_arrays is not None and "ml" in method:
-                t, p, e = fns.select_fit_pred(
+                launched = fns.select_fit_pred(
                     values, moments, rep_for_point, is_rep,
                     self._tree_arrays, padded_g,
                 )
             else:
-                t, p, e = fns.select_fit_all(
+                launched = fns.select_fit_all(
                     values, moments, rep_for_point, is_rep, padded_g
                 )
-            return np.asarray(t), np.asarray(p), np.asarray(e), g, 0
-
-        # reuse / reuse_ml: device dedup + host cache — only the compacted
-        # (G,) rep keys/rows and the (P,) slot map come down, then the
-        # representative-fit core runs exactly as on the host path.
-        gather_idx, rep_keys4, point_slot = fns.compact(
-            moments, rep_for_point, is_rep, padded_g
-        )
-        rep_rows = np.asarray(gather_idx)[:g].astype(np.int64)
-        rep_keys = grp.keys_to_int64(np.asarray(rep_keys4)[:g])  # (G, 2) int64
-        rep_t, rep_p, rep_e, fitted, cache_hits = self._fit_representatives(
-            values, moments, rep_keys, rep_rows
-        )
-        inv = np.asarray(point_slot)
-        return rep_t[inv], rep_p[inv], rep_e[inv], fitted, cache_hits
+        self.spans.count("fit_rows", g)
+        self.spans.count("fit_rows_padded", padded_g)
+        with self.spans.span("pdf.fit.wait", start=launch.end, **ids):
+            t, p, e = (np.asarray(x) for x in launched)
+        return t, p, e, g, 0
 
     def _sample_seed(self, w: regions.Window) -> int:
         """Per-window draw seed from (sample_seed, slice, line): results do
@@ -1154,11 +1234,135 @@ class StagedExecutor:
         filtered against each slice's watermark here and their results
         restored from the persisted ``.npz`` files.
         """
+        ppl = self.data.geometry.points_per_line
+        requested = plan.slices
+        snapshot = self.spans.snapshot()
+        edge_ids = {"slice": requested[0] if len(requested) == 1 else -1,
+                    "line": -1}
+        with self.spans.span("pdf.slice.open", **edge_ids):
+            persist, outs, units, stream, prefetcher, wall0 = self._open(
+                plan, resume)
+        stats: dict[int, list[WindowStats]] = {s: [] for s in requested}
+        quarantined: dict[int, list[dict]] = {s: [] for s in requested}
+        load_total = wait_total = compute_total = 0.0
+        k = 0  # units taken off the stream: the next one is units[k]
+        try:
+            while True:
+                wait_ids = (_ids(units[k].window) if k < len(units)
+                            else edge_ids)
+                with self.spans.span("pdf.load.wait", **wait_ids) as wait:
+                    try:
+                        item = next(stream, None)
+                    except PrefetchError as pe:
+                        # Shard death must surface as itself: the
+                        # scheduler's re-deal catches ShardLostError, not
+                        # the prefetch wrapper it crossed the thread
+                        # boundary in.
+                        if isinstance(pe.__cause__, ShardLostError):
+                            raise pe.__cause__
+                        raise
+                if item is None:
+                    break
+                k += 1
+                # wait_s: the only load-stage time the device was blocked on
+                # (serial mode does the whole load inline here, so wait ==
+                # load by construction; with prefetch it is the shortfall).
+                wait_s = wait.seconds
+
+                if not isinstance(item, _FailedUnit):
+                    item = self._compute_with_retry(item)
+                if isinstance(item, _FailedUnit):
+                    if not self.exec_config.degraded_mode:
+                        raise RuntimeError(
+                            f"work unit {item.unit.unit_id} failed after "
+                            f"{item.attempts} attempts: {item.error}")
+                    self._quarantine(item, outs, ppl, quarantined)
+                    continue
+
+                with self.spans.span("pdf.handoff", **_ids(item.window)):
+                    (w, t, p, e, moments, sample_idx, fitted, hits,
+                     comp_s, _load_s) = item
+                    mom_np = _moments_np(moments)
+                    o = outs[w.slice_i]
+                    lo, hi = w.line_start * ppl, w.line_end * ppl
+                    o["type_idx"][lo:hi], o["params"][lo:hi], o["error"][lo:hi] = t, p, e
+                    if sample_idx is None:
+                        for name, col in zip(("mean", "std", "skew", "kurt"), mom_np):
+                            o[name][lo:hi] = col
+                    else:
+                        # random sampling computed moments for the sampled
+                        # rows only; unsampled rows stay zero (their
+                        # type_idx is -1)
+                        for name, col in zip(("mean", "std", "skew", "kurt"), mom_np):
+                            o[name][lo:hi][sample_idx] = col
+
+                    ws = WindowStats(w, hi - lo, fitted, item.load_seconds,
+                                     comp_s, hits, wait_s)
+                    stats[w.slice_i].append(ws)
+                    load_total += item.load_seconds
+                    wait_total += wait_s
+                    compute_total += comp_s
+                    self.spans.count("windows")
+                    self.spans.count("points", hi - lo)
+
+                    persist.submit(
+                        w.slice_i, w, {name: o[name][lo:hi] for name in _FIELDS}
+                    )
+                    if on_window:
+                        on_window(ws)
+        except BaseException:
+            with self.spans.span("pdf.slice.drain", **edge_ids):
+                self._close(prefetcher, persist)
+            raise
+
+        with self.spans.span("pdf.slice.drain", **edge_ids):
+            self._close(prefetcher, persist)
+            persist.raise_if_failed()
+            if self.out_dir is not None:
+                for s in requested:
+                    persist.write_failed_manifest(s, quarantined[s])
+            wall = time.perf_counter() - wall0
+            counts = self._fault_counts
+            results: dict[int, SliceResult] = {}
+            for s in requested:
+                o = outs[s]
+                avg_err = float(o["error"].mean())
+                c = counts.get(s, {})
+                r = SliceResult(o["type_idx"], o["params"], o["error"], o["mean"],
+                                o["std"], o["skew"], o["kurt"], avg_err, stats[s],
+                                slice_i=s, spec_hash=self.spec_hash,
+                                retries=c.get("retries", 0),
+                                speculations=c.get("speculations", 0),
+                                quarantined=tuple(quarantined[s]))
+                if self.config.error_bound is not None:
+                    r.error_bound_satisfied = avg_err <= self.config.error_bound
+                results[s] = r
+        spans, counters = self.spans.since(snapshot)
+        self.last_report = ExecutorReport(
+            wall_seconds=wall,
+            units=sum(len(v) for v in stats.values()),
+            load_seconds=load_total,
+            wait_seconds=wait_total,
+            compute_seconds=compute_total,
+            persist_seconds=persist.seconds,
+            retries=sum(c["retries"] for c in counts.values()),
+            speculations=sum(c["speculations"] for c in counts.values()),
+            speculation_wins=sum(
+                c["speculation_wins"] for c in counts.values()),
+            quarantined=sum(len(v) for v in quarantined.values()),
+            spans=spans,
+            counters=counters,
+        )
+        return results
+
+    def _open(self, plan: regions.Plan, resume: bool):
+        """A run's set-up (the ``pdf.slice.open`` span): the persist stage,
+        the output buffers, the units left after resume, and the load
+        stream (the prefetcher started)."""
         geom = self.data.geometry
         ppl = geom.points_per_line
         total = geom.points_per_slice
         requested = plan.slices
-
         persist = PersistStage(
             self.out_dir,
             async_writes=self.exec_config.async_persist,
@@ -1166,6 +1370,7 @@ class StagedExecutor:
             spec_hash=self.spec_hash,
             injector=self.injector,
             total_lines=geom.lines_per_slice,
+            spans=self.spans,
         )
 
         outs = {
@@ -1180,7 +1385,6 @@ class StagedExecutor:
             }
             for s in requested
         }
-        stats: dict[int, list[WindowStats]] = {s: [] for s in requested}
 
         units = list(plan.units)
         if resume and self.out_dir is not None:
@@ -1207,8 +1411,6 @@ class StagedExecutor:
         # LOCK rule's first true positive)
         with self._fault_lock:
             self._fault_counts = {}
-        quarantined: dict[int, list[dict]] = {s: [] for s in requested}
-        load_total = wait_total = compute_total = 0.0
         wall0 = time.perf_counter()
         prefetcher = None
         if self.exec_config.prefetch and units:
@@ -1218,105 +1420,17 @@ class StagedExecutor:
             stream = iter(prefetcher)
         else:
             stream = (self._load_guarded(u) for u in units)
+        return persist, outs, units, stream, prefetcher, wall0
 
-        try:
-            while True:
-                w0 = time.perf_counter()
-                try:
-                    item = next(stream, None)
-                except PrefetchError as pe:
-                    # Shard death must surface as itself: the scheduler's
-                    # re-deal catches ShardLostError, not the prefetch
-                    # wrapper it crossed the thread boundary in.
-                    if isinstance(pe.__cause__, ShardLostError):
-                        raise pe.__cause__
-                    raise
-                if item is None:
-                    break
-                # wait_s: the only load-stage time the device was blocked on
-                # (serial mode does the whole load inline here, so wait ==
-                # load by construction; with prefetch it is the shortfall).
-                wait_s = time.perf_counter() - w0
-
-                if not isinstance(item, _FailedUnit):
-                    item = self._compute_with_retry(item)
-                if isinstance(item, _FailedUnit):
-                    if not self.exec_config.degraded_mode:
-                        raise RuntimeError(
-                            f"work unit {item.unit.unit_id} failed after "
-                            f"{item.attempts} attempts: {item.error}")
-                    self._quarantine(item, outs, ppl, quarantined)
-                    continue
-
-                (w, t, p, e, mom_np, sample_idx, fitted, hits,
-                 comp_s, _load_s) = item
-                o = outs[w.slice_i]
-                lo, hi = w.line_start * ppl, w.line_end * ppl
-                o["type_idx"][lo:hi], o["params"][lo:hi], o["error"][lo:hi] = t, p, e
-                if sample_idx is None:
-                    for name, col in zip(("mean", "std", "skew", "kurt"), mom_np):
-                        o[name][lo:hi] = col
-                else:
-                    # random sampling computed moments for the sampled rows
-                    # only; unsampled rows stay zero (their type_idx is -1)
-                    for name, col in zip(("mean", "std", "skew", "kurt"), mom_np):
-                        o[name][lo:hi][sample_idx] = col
-
-                ws = WindowStats(w, hi - lo, fitted, item.load_seconds,
-                                 comp_s, hits, wait_s)
-                stats[w.slice_i].append(ws)
-                load_total += item.load_seconds
-                wait_total += wait_s
-                compute_total += comp_s
-
-                persist.submit(
-                    w.slice_i, w, {name: o[name][lo:hi] for name in _FIELDS}
-                )
-                if on_window:
-                    on_window(ws)
-        finally:
-            if prefetcher is not None:
-                prefetcher.close()
-            persist.close()  # flushes: the watermark is durable before any re-raise
-            if self._spec_pool is not None:
-                self._spec_pool.shutdown(wait=False, cancel_futures=True)
-                self._spec_pool = None
-
-        persist.raise_if_failed()
-        if self.out_dir is not None:
-            for s in requested:
-                persist.write_failed_manifest(s, quarantined[s])
-        wall = time.perf_counter() - wall0
-        counts = self._fault_counts
-        self.last_report = ExecutorReport(
-            wall_seconds=wall,
-            units=sum(len(v) for v in stats.values()),
-            load_seconds=load_total,
-            wait_seconds=wait_total,
-            compute_seconds=compute_total,
-            persist_seconds=persist.seconds,
-            retries=sum(c["retries"] for c in counts.values()),
-            speculations=sum(c["speculations"] for c in counts.values()),
-            speculation_wins=sum(
-                c["speculation_wins"] for c in counts.values()),
-            quarantined=sum(len(v) for v in quarantined.values()),
-        )
-
-        results: dict[int, SliceResult] = {}
-        for s in requested:
-            o = outs[s]
-            avg_err = float(o["error"].mean())
-            c = counts.get(s, {})
-            r = SliceResult(o["type_idx"], o["params"], o["error"], o["mean"],
-                            o["std"], o["skew"], o["kurt"], avg_err, stats[s],
-                            slice_i=s, spec_hash=self.spec_hash,
-                            retries=c.get("retries", 0),
-                            speculations=c.get("speculations", 0),
-                            quarantined=tuple(quarantined[s]))
-            if self.config.error_bound is not None:
-                r.error_bound_satisfied = avg_err <= self.config.error_bound
-            results[s] = r
-        return results
+    def _close(self, prefetcher, persist: "PersistStage"):
+        """Stop the load stage and flush the persist stage: the watermark
+        is durable before the run returns or re-raises."""
+        if prefetcher is not None:
+            prefetcher.close()
+        persist.close()
+        if self._spec_pool is not None:
+            self._spec_pool.shutdown(wait=False, cancel_futures=True)
+            self._spec_pool = None
 
     class _ComputedWindow(NamedTuple):
         """One computed window: everything the run loop scatters/persists."""
@@ -1325,7 +1439,7 @@ class StagedExecutor:
         type_idx: np.ndarray
         params: np.ndarray
         error: np.ndarray
-        mom_np: tuple
+        moments: tuple  # device (mean, var, skew, kurt, ...)
         sample_idx: np.ndarray | None
         fitted: int
         cache_hits: int
@@ -1351,29 +1465,30 @@ class StagedExecutor:
             # with the rate. k-means keeps the full pass: it clusters on
             # every point's (mu, sigma) by construction.
             sample_idx = self._draw_sample(total_points, w)
-            values = values[jnp.asarray(sample_idx)]
-        moments = jax.block_until_ready(self._moments(values))
+        ids = _ids(w)
+        with self.spans.span("pdf.moments", **ids) as sp:
+            if sample_idx is not None:
+                values = values[jnp.asarray(sample_idx)]
+            moments = jax.block_until_ready(self._moments(values))
         if self.stats_recorder is not None and sample_idx is None:
             # Must run before _select_and_fit: the fit executables donate
             # ``values``. Sampled windows are skipped — their stats describe
             # a draw, not the window, and cannot merge with append data.
-            self.stats_recorder(w, values, dists.Moments(*moments))
-        t1 = time.perf_counter()
+            with self.spans.span("pdf.stats", **ids) as sp:
+                self.stats_recorder(w, values, dists.Moments(*moments))
+        t1 = sp.end  # the compute stage starts where the last span ended
         cmon.start(uid, now=t1)
         try:
             t, p, e, fitted, hits = self._select_and_fit(
                 values, dists.Moments(*moments), w,
-                sample_idx=sample_idx, total_points=total_points,
+                sample_idx=sample_idx, total_points=total_points, start=t1,
             )
         except BaseException:
             cmon.abandon(uid)
             raise
         t2 = time.perf_counter()
         cmon.finish(uid, now=t2)
-        mom_np = (np.asarray(moments[0]),
-                  np.sqrt(np.maximum(np.asarray(moments[1]), 0)),
-                  np.asarray(moments[2]), np.asarray(moments[3]))
-        return self._ComputedWindow(w, t, p, e, mom_np, sample_idx, fitted,
+        return self._ComputedWindow(w, t, p, e, moments, sample_idx, fitted,
                                     hits, t2 - t1, item.load_seconds)
 
     def _compute_with_retry(self, item: _StagedWindow):
@@ -1514,7 +1629,7 @@ class StagedExecutor:
                 fits = [self._fit_all(v, m) for v, m in zip(staged, moments)]
             per = [tuple(np.asarray(x) for x in f) for f in fits]
         else:
-            per = self._select_and_fit_packed(staged, moments)
+            per = self._select_and_fit_packed(windows, staged, moments)
         cmon.finish(uid, now=time.perf_counter())
 
         out = []
@@ -1526,7 +1641,8 @@ class StagedExecutor:
                 np.asarray(m.skew), np.asarray(m.kurt)))
         return out
 
-    def _select_and_fit_packed(self, staged: list, moments: list):
+    def _select_and_fit_packed(self, windows: list, staged: list,
+                               moments: list):
         """Grouped Select over a window batch: quantize + dedup per window
         on host (grouping scope = the window, as Algorithm 3 defines it),
         then pack whole windows of the same serial fit-shape class into
@@ -1574,7 +1690,7 @@ class StagedExecutor:
                 pos += n
             sub_vals, sub_mom = self._gather(cat_vals, cat_mom,
                                              jnp.asarray(idx))
-            t, p, e = self._fit(sub_vals, sub_mom)
+            t, p, e = self._fit(sub_vals, sub_mom, _ids(windows[idxs[0]]))
             pos = 0
             for i in idxs:
                 g = infos[i]
@@ -1607,9 +1723,7 @@ class StagedExecutor:
             sample_idx=sample_idx, total_points=total_points,
         )
         cmon.finish(uid, now=time.perf_counter())
-        mom_np = (np.asarray(moments[0]),
-                  np.sqrt(np.maximum(np.asarray(moments[1]), 0)),
-                  np.asarray(moments[2]), np.asarray(moments[3]))
+        mom_np = _moments_np(moments)
         if sample_idx is None:
             mean, std, skew, kurt = mom_np
         else:

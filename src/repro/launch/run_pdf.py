@@ -195,6 +195,14 @@ def _run_once(session: PDFSession, spec: PipelineSpec) -> None:
     else:
         print(f"[total] wall={wall:.3f}s windows={rep.windows} "
               f"spec={rep.spec_hash}")
+    # the session's named host spans (runtime.monitor.SpanRecorder): time
+    # per window and how many times each ran, then the work counters
+    for name, (secs, n) in sorted(rep.spans.items()):
+        print(f"[span] {name} ms_per_window="
+              f"{secs * 1e3 / max(rep.windows, 1):.3f} count={n}")
+    if rep.counters:
+        print("[counters] " + " ".join(
+            f"{k}={v}" for k, v in sorted(rep.counters.items())))
 
 
 if __name__ == "__main__":

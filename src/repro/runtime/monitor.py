@@ -10,13 +10,22 @@ deterministic).
 
 On a real cluster the same monitor ingests per-host heartbeats; here it is
 driven by the single-process loops and unit-tested with synthetic timings.
+
+``SpanRecorder`` names the host work between heartbeats: each span is a
+``jax.profiler.TraceAnnotation`` (so a profiler trace shows it on the same
+clock as the device operations, on the thread that ran it) whose two
+``perf_counter`` reads also feed in-memory totals per span name, and the
+heartbeats and stage totals take their times from those same reads.
 """
 
 from __future__ import annotations
 
+import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
+
+from jax.profiler import TraceAnnotation
 
 # Trailing-latency reservoir per monitor: enough samples for stable p99 at
 # serving rates while bounding memory on long-lived daemons (a PDFServer's
@@ -112,3 +121,91 @@ class StepMonitor:
             if u not in self.flagged:
                 self.flagged.append(u)
         return out
+
+
+class Span:
+    """One open span: ``start`` is its first ``perf_counter`` read, ``end``
+    its last (set when the ``with`` block exits)."""
+
+    __slots__ = ("_recorder", "_name", "_annotation", "start", "end")
+
+    def __init__(self, recorder: "SpanRecorder", name: str, start: float | None,
+                 ids: dict):
+        self._recorder = recorder
+        self._name = name
+        self._annotation = TraceAnnotation(name, **ids)
+        self.start = start
+        self.end: float | None = None
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+    def __enter__(self) -> "Span":
+        self._annotation.__enter__()
+        if self.start is None:
+            self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        self._recorder._add(self._name, self.end - self.start)
+
+
+class SpanRecorder:
+    """Totals of named host spans and counters, shared by the threads of
+    one executor or session (the prefetch and writer threads record into
+    the same recorder as the main thread).
+
+    ``span(name, **ids)`` opens a profiler annotation carrying ``ids`` as
+    its arguments (the executor passes ``slice`` and ``line``, which link a
+    window's spans across threads) and adds the span's duration and a count
+    to ``spans[name]``. ``count(name, n)`` adds to ``counters[name]``."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.spans: dict[str, tuple[float, int]] = {}
+        self.counters: dict[str, int] = {}
+
+    def span(self, name: str, start: float | None = None, **ids) -> Span:
+        """``start``: begin at a ``perf_counter`` read already taken (the
+        end of the span before), so adjacent spans share the read and tile
+        the time between them with no gap."""
+        return Span(self, name, start, ids)
+
+    def _add(self, name: str, seconds: float) -> None:
+        with self._lock:
+            total, n = self.spans.get(name, (0.0, 0))
+            self.spans[name] = (total + seconds, n + 1)
+
+    def count(self, name: str, n: int = 1) -> None:
+        with self._lock:
+            self.counters[name] = self.counters.get(name, 0) + int(n)
+
+    def snapshot(self) -> tuple[dict, dict]:
+        with self._lock:
+            return dict(self.spans), dict(self.counters)
+
+    def since(self, snapshot: tuple[dict, dict]) -> tuple[dict, dict]:
+        """``(spans, counters)`` recorded after ``snapshot``."""
+        spans0, counters0 = snapshot
+        spans, counters = self.snapshot()
+        d_spans = {}
+        for name, (total, n) in spans.items():
+            t0, n0 = spans0.get(name, (0.0, 0))
+            if n > n0:
+                d_spans[name] = (total - t0, n - n0)
+        d_counters = {k: v - counters0.get(k, 0) for k, v in counters.items()
+                      if v != counters0.get(k, 0)}
+        return d_spans, d_counters
+
+
+def merge_totals(into_spans: dict, into_counters: dict, spans: dict,
+                 counters: dict) -> None:
+    """Add one ``(spans, counters)`` pair into running totals."""
+    for name, (total, n) in spans.items():
+        t0, n0 = into_spans.get(name, (0.0, 0))
+        into_spans[name] = (t0 + total, n0 + n)
+    for name, v in counters.items():
+        into_counters[name] = into_counters.get(name, 0) + v

@@ -15,18 +15,25 @@ per-window durations feed ``StepMonitor`` instances so straggler flagging
 This module deliberately does not import the executor: any object with
 ``data.geometry``, ``config.window_lines`` and ``run(plan, resume=...,
 on_window=...)`` schedules fine, which also keeps the import graph acyclic
-(core.executor already depends on runtime.monitor).
+(core.executor already depends on runtime.monitor). For the same reason
+``core.regions`` is imported where it is used: importing it at the top
+would import the whole ``repro.core`` package (executor included) with
+``repro.runtime``, and a thread importing ``repro.core`` while another
+imports ``repro.runtime`` could then be handed a partly initialized
+module.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable, Mapping, Sequence
+from typing import TYPE_CHECKING, Callable, Mapping, Sequence
 
-from repro.core import regions
 from repro.runtime import elastic
 from repro.runtime.faults import ShardLostError
 from repro.runtime.monitor import StepMonitor, StragglerPolicy
+
+if TYPE_CHECKING:
+    from repro.core import regions
 
 
 @dataclass(frozen=True)
@@ -89,6 +96,8 @@ class SliceScheduler:
         self, geom: regions.CubeGeometry, slices: Sequence[int],
         window_lines: int, shard: int,
     ) -> regions.Plan:
+        from repro.core import regions
+
         a = self.assignments(slices)[shard]
         return regions.build_plan(geom, a.slices, window_lines)
 
@@ -165,6 +174,8 @@ class SliceScheduler:
         resume: bool,
         on_window: Callable | None,
     ) -> Mapping[int, object]:
+        from repro.core import regions
+
         ex = executor_factory(shard)
         wl = window_lines if window_lines is not None else ex.config.window_lines
         plan = regions.build_plan(ex.data.geometry, shard_slices, wl)

@@ -55,3 +55,23 @@ def test_plan_remesh_impossible_raises():
     old = ElasticPlan(data=1, model=1, pods=1, grad_accum=1)
     with pytest.raises(ValueError):
         plan_remesh(1, model_divisors=(8,), target_global_batch=8, old_plan=old)
+
+
+
+@pytest.mark.parametrize("modules,absent", [
+    ("repro.runtime, repro.runtime.cluster", "repro.core."),
+    ("repro.core.regions, repro.data", "repro.core.executor"),
+])
+def test_imports_leave_the_executor_out(modules, absent):
+    # repro.core.executor imports repro.runtime and repro.data; if importing
+    # those (or one core submodule) imported the executor back, a thread
+    # importing one end of the cycle while another imports the other could
+    # be handed a partly initialized module
+    import subprocess
+    import sys
+
+    code = (f"import sys, {modules}; "
+            f"print(sorted(m for m in sys.modules if m.startswith({absent!r})))")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         timeout=120, check=True)
+    assert out.stdout.strip() == "[]", out.stdout + out.stderr
