@@ -9,6 +9,10 @@ is that layer for the JAX reproduction:
                  and staging it host->device while the device is still
                  fitting window *k* (device work, including the moments
                  kernel, stays on the compute stage — see _StagedWindow).
+                 A source that reads into ``out`` (FileCubeSource) fills a
+                 host buffer recycled from the executor's free-list; the
+                 load waits for that window's transfer to land before the
+                 buffer goes back.
   compute stage  the main thread: Select (grouping / reuse / ML dispatch)
                  on host + batched ComputePDF&Error on device — identical
                  operations, in identical order, to the old serial loop, so
@@ -37,7 +41,7 @@ inside ``pdf.load.wait``). The heartbeats and the report's stage totals take the
 from the spans' own clock reads; ``ExecutorReport.spans`` and ``counters``
 carry the run's span totals and work counts (``windows``, ``points``,
 ``fit_rows``, ``fit_rows_padded``, ``groups``, ``bytes_read``,
-``bytes_h2d``).
+``bytes_h2d``, ``read_recycled``).
 
 ``PDFComputer`` (pipeline.py) is a thin facade over this executor; the
 multi-slice entry point is ``run`` on a ``regions.Plan``, which
@@ -48,6 +52,7 @@ from __future__ import annotations
 
 import functools
 import hashlib
+import inspect
 import json
 import queue
 import threading
@@ -496,6 +501,18 @@ class _FailedUnit(NamedTuple):
     attempts: int
 
 
+# Host read buffers an executor keeps for reuse: one is in flight per
+# loading thread (the prefetcher, or a speculation pair).
+_FREE_BUFFERS = 4
+
+
+def _takes_out(source) -> bool:
+    """Whether ``source.load_window`` reads into a caller's ``out`` (an
+    executor may be built without a source, to run none)."""
+    load = getattr(source, "load_window", None)
+    return load is not None and "out" in inspect.signature(load).parameters
+
+
 def _ids(w: regions.Window) -> dict:
     """A window's span arguments: its slice and first line, which link the
     window's spans across the prefetch, main and writer threads."""
@@ -820,6 +837,12 @@ class StagedExecutor:
         self._fault_lock = threading.Lock()
         self._fault_counts: dict[int, dict[str, int]] = {}
         self._spec_pool: futures.ThreadPoolExecutor | None = None
+        # Host buffers for sources whose load_window reads into ``out``
+        # (FileCubeSource): flat float32, each back on this list only once
+        # the transfer staged from it has landed (_load_unit).
+        self._fills_out = _takes_out(data_source)
+        self._free_buffers: list[np.ndarray] = []
+        self._free_lock = threading.Lock()
 
     # -- load stage -----------------------------------------------------------
 
@@ -833,6 +856,42 @@ class StagedExecutor:
         self.spans.count("bytes_h2d", staged.nbytes)
         return staged
 
+    def _new_buffer(self, size: int) -> np.ndarray:
+        return np.empty(size, np.float32)
+
+    def _read(self, w: regions.Window) -> tuple[np.ndarray | None, np.ndarray]:
+        """``(buffer, window)``: the window read into a host buffer taken
+        from the free-list (counted ``read_recycled``) or made anew, where
+        the source reads into ``out``; else ``(None, fresh array)``."""
+        if not self._fills_out:
+            return None, self.data.load_window(w)  # (P, n_obs)
+        shape = (w.num_lines * self.data.geometry.points_per_line,
+                 self.data.slice_observations(w.slice_i))
+        size = shape[0] * shape[1]
+        with self._free_lock:
+            fits = [i for i, b in enumerate(self._free_buffers) if b.size >= size]
+            buf = self._free_buffers.pop(fits[-1]) if fits else None
+        recycled = buf is not None
+        if buf is None:
+            buf = self._new_buffer(size)
+        raw = self.data.load_window(w, out=buf[:size].reshape(shape))
+        if recycled:
+            self.spans.count("read_recycled")
+        return buf, raw
+
+    def _give_back(self, buf: np.ndarray, staged: jax.Array) -> None:
+        """Return ``buf`` to the free-list once ``staged``'s transfer has
+        landed — unless the runtime made the device array a view of it (the
+        CPU backend may, for an aligned buffer): then the array owns it."""
+        lo = buf.ctypes.data
+        hi = lo + buf.nbytes
+        if any(lo <= s.data.unsafe_buffer_pointer() < hi
+               for s in staged.addressable_shards):
+            return
+        with self._free_lock:
+            if len(self._free_buffers) < _FREE_BUFFERS:
+                self._free_buffers.append(buf)
+
     def _load_unit(self, unit: regions.WorkUnit,
                    uid: str | None = None) -> _StagedWindow:
         """Load + H2D-stage one window (host work only — device kernels stay
@@ -840,17 +899,23 @@ class StagedExecutor:
         enabled, or on speculation-pool threads under re-dispatch. ``uid``
         distinguishes attempts of the same unit in the load monitor; failed
         attempts are abandoned (no duration recorded) so an injected stall
-        cannot poison the straggler median."""
+        cannot poison the straggler median. A window read into a recycled
+        buffer waits here for its transfer to land (inside ``pdf.load.h2d``)
+        before the buffer goes back; two loads of one window (speculation)
+        take two buffers."""
         mon = self.monitors["load"]
         uid = uid or unit.unit_id
         ids = _ids(unit.window)
         try:
             with self.spans.span("pdf.load.read", **ids) as read:
                 mon.start(uid, now=read.start)
-                raw = self.data.load_window(unit.window)  # (P, n_obs)
+                buf, raw = self._read(unit.window)
             self.spans.count("bytes_read", raw.nbytes)
             with self.spans.span("pdf.load.h2d", start=read.end, **ids) as h2d:
                 values = self._stage(raw)
+                if buf is not None:
+                    values.block_until_ready()
+                    self._give_back(buf, values)
         except BaseException:
             mon.abandon(uid)
             raise

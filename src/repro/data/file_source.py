@@ -10,10 +10,13 @@ for the reproduction:
     directory of chunked ``.npy`` files plus a ``manifest.json``, so a
     simulation *spec* becomes real bytes on disk once and every later run
     reads those bytes instead of regenerating them;
-  * ``FileCubeSource`` is the window reader: ``load_window`` memmaps only
-    the chunks a window overlaps (a window read touches O(window) bytes, not
-    the cube), so it plugs straight into ``WindowPrefetcher`` prefetching and
-    the ``ThrottledSource`` NFS-bandwidth model like every other source;
+  * ``FileCubeSource`` is the window reader: ``load_window`` reads only
+    the bytes of the chunks a window overlaps (O(window) bytes, not the
+    cube) — by positional reads straight into the caller's buffer where a
+    chunk's rows are whole rows of the window, else by a strided copy out
+    of the chunk's memmap — so it plugs straight into ``WindowPrefetcher``
+    prefetching and the ``ThrottledSource`` NFS-bandwidth model like every
+    other source;
   * the manifest carries a per-chunk sha256 and a ``content_sha256`` over
     the whole description — the cube's *data identity*. ``SourceSpec``
     (``kind='file'``) hashes by that digest, so a spec's ``content_hash``
@@ -41,6 +44,7 @@ import json
 import os
 import threading
 from collections import OrderedDict
+from concurrent import futures
 from pathlib import Path
 from typing import Callable
 
@@ -62,11 +66,57 @@ SUPPORTED_FORMAT_VERSIONS = (1, 2)
 LAYOUTS = ("chunked",)
 DEFAULT_LINES_PER_CHUNK = 16
 
-# How many chunk memmaps a reader keeps open at once. Sequential window
-# reads touch a sliding band of chunks, so a small LRU is enough; the cap
-# keeps a paper-scale cube (thousands of chunks) from exhausting file
-# descriptors.
+# How many chunk memmaps, and how many chunk file descriptors, a reader
+# keeps open at once. Sequential window reads touch a sliding band of
+# chunks, so a small LRU is enough; the cap keeps a paper-scale cube
+# (thousands of chunks) from exhausting file descriptors.
 _MMAP_CACHE_SIZE = 64
+
+
+class _ChunkFile:
+    """A chunk file open for positional reads, closed when the last
+    reference to it goes: a descriptor the reader's LRU evicts stays open
+    while a read on another thread still holds it."""
+
+    __slots__ = ("fd",)
+
+    def __init__(self, fd: int):
+        self.fd = fd
+
+    def __del__(self, _close=os.close):
+        _close(self.fd)
+
+
+def _pread_into(f: _ChunkFile, offset: int, dst: memoryview, name) -> None:
+    """Fill ``dst`` from the file at ``offset`` (``os.preadv`` releases the
+    GIL for the copy, so the other half and the main thread run on)."""
+    while len(dst):
+        n = os.preadv(f.fd, [dst], offset)
+        if n == 0:
+            raise ValueError(
+                f"cube chunk {name}: file ends {len(dst)} bytes short of "
+                "the manifest's shape")
+        dst, offset = dst[n:], offset + n
+
+
+def _read_pieces(pieces: list) -> None:
+    for f, offset, dst, name in pieces:
+        _pread_into(f, offset, dst, name)
+
+
+def _halves(pieces: list, nbytes: int) -> tuple[list, list]:
+    """Split ``(file, offset, dst, name)`` pieces at the byte ``nbytes //
+    2`` of their destinations taken in order: two disjoint reads of near
+    equal size."""
+    first, second, done = [], [], 0
+    for f, offset, dst, name in pieces:
+        k = min(len(dst), max(0, nbytes // 2 - done))
+        if k:
+            first.append((f, offset, dst[:k], name))
+        if k < len(dst):
+            second.append((f, offset + k, dst[k:], name))
+        done += len(dst)
+    return first, second
 
 
 def _chunk_name(slice_i: int, line_start: int) -> str:
@@ -284,13 +334,20 @@ def export_cube(
 class FileCubeSource:
     """Window reader over an exported cube directory.
 
-    ``load_window(w) -> (num_points, n_obs) float32``, bit-identical to what
-    the exported source produced (tests/test_file_source.py asserts the
-    round-trip against the simulation, and through the full pipeline).
-    Reads memmap only the chunks the window overlaps and copy them into a
-    fresh array — the copy forces the actual page-in, so a wrapping
-    ``ThrottledSource`` times real bytes moved, and the buffer handed to the
-    prefetcher is safe to donate.
+    ``load_window(w, out=None) -> (num_points, n_obs) float32``,
+    bit-identical to what the exported source produced
+    (tests/test_file_source.py asserts the round-trip against the
+    simulation, and through the full pipeline). The window lands in ``out``
+    when given (the executor passes a host buffer it recycles), else in a
+    fresh array. Where the requested observations are a chunk's whole row
+    width, the window's lines in that chunk are one contiguous byte range
+    after the ``.npy`` header: it is read by ``os.preadv`` on a descriptor
+    the reader keeps open, straight into the destination, as two halves
+    read at once (one on a helper thread), and no memmap is opened. Other
+    chunks (an observation sub-range: the streaming delta read, or a slice
+    whose appended layers make each chunk a part of the row) are memmapped
+    and copied strided. Either way the bytes are really moved, so a
+    wrapping ``ThrottledSource`` times real reads.
 
     ``enable_read_verification()`` arms *verified reads*: every chunk a
     window touches is fully loaded (no memmap) and re-hashed against the
@@ -363,6 +420,12 @@ class FileCubeSource:
                     c["line_start"]))
             self._chunks[s] = lst
         self._mmaps: OrderedDict[str, np.ndarray] = OrderedDict()
+        self._files: OrderedDict[str, _ChunkFile] = OrderedDict()
+        self._data_offsets: dict[str, int] = {}  # .npy header length
+        # Reads the second half of each positional window read (two
+        # workers: speculation may read two windows at once).
+        self._helper = futures.ThreadPoolExecutor(
+            max_workers=2, thread_name_prefix="cube-read")
         # Speculative re-dispatch (core.executor) can read two windows of
         # one source from two threads; the LRU mutations must not race.
         self._mmap_lock = threading.Lock()
@@ -383,6 +446,17 @@ class FileCubeSource:
             self.read_hook = read_hook
         return self
 
+    def _check_chunk(self, entry: dict, shape, dtype,
+                     fortran: bool = False) -> None:
+        o0, o1 = chunk_obs_range(entry, self.num_observations)
+        expect = (entry["line_end"] - entry["line_start"],
+                  self.geometry.points_per_line, o1 - o0)
+        if tuple(shape) != expect or dtype != np.float32 or fortran:
+            raise ValueError(
+                f"cube chunk {entry['file']}: shape {tuple(shape)} dtype "
+                f"{dtype}{' (Fortran order)' if fortran else ''} does not "
+                f"match manifest ({expect}, float32)")
+
     def _mmap(self, entry: dict) -> np.ndarray:
         name = entry["file"]
         with self._mmap_lock:
@@ -390,18 +464,39 @@ class FileCubeSource:
                 self._mmaps.move_to_end(name)
                 return self._mmaps[name]
         arr = np.load(self.path / name, mmap_mode="r")
-        o0, o1 = chunk_obs_range(entry, self.num_observations)
-        expect = (entry["line_end"] - entry["line_start"],
-                  self.geometry.points_per_line, o1 - o0)
-        if arr.shape != expect or arr.dtype != np.float32:
-            raise ValueError(
-                f"cube chunk {name}: shape {arr.shape} dtype {arr.dtype} "
-                f"does not match manifest ({expect}, float32)")
+        self._check_chunk(entry, arr.shape, arr.dtype)
         with self._mmap_lock:
             self._mmaps[name] = arr
             if len(self._mmaps) > _MMAP_CACHE_SIZE:
                 self._mmaps.popitem(last=False)
         return arr
+
+    def _open(self, entry: dict) -> tuple[_ChunkFile, int]:
+        """The chunk's open file and the byte offset of its data, from its
+        ``.npy`` header, checked against the manifest on first use."""
+        name = entry["file"]
+        with self._mmap_lock:
+            f = self._files.get(name)
+            if f is not None:
+                self._files.move_to_end(name)
+                return f, self._data_offsets[name]
+        offset = self._data_offsets.get(name)
+        if offset is None:
+            fmt = np.lib.format
+            with open(self.path / name, "rb") as fh:
+                major, _minor = fmt.read_magic(fh)
+                read_header = (fmt.read_array_header_1_0 if major == 1
+                               else fmt.read_array_header_2_0)
+                shape, fortran, dtype = read_header(fh)
+                offset = fh.tell()
+            self._check_chunk(entry, shape, dtype, fortran)
+        f = _ChunkFile(os.open(self.path / name, os.O_RDONLY))
+        with self._mmap_lock:
+            self._data_offsets[name] = offset
+            self._files[name] = f
+            if len(self._files) > _MMAP_CACHE_SIZE:
+                self._files.popitem(last=False)
+        return f, offset
 
     def _read_chunk_verified(self, entry: dict) -> np.ndarray:
         """Fully load one chunk and check its sha256 against the manifest.
@@ -426,18 +521,21 @@ class FileCubeSource:
                     f"{attempts} read attempts: sha256 {got} != "
                     f"manifest {entry['sha256']}")
 
-    def load_window(self, w: Window) -> np.ndarray:
+    def load_window(self, w: Window,
+                    out: np.ndarray | None = None) -> np.ndarray:
         if w.slice_i not in self._slice_obs:
             raise ValueError(f"window {w} outside cube {self.geometry}")
-        return self.load_window_obs(w, 0, self._slice_obs[w.slice_i])
+        return self.load_window_obs(w, 0, self._slice_obs[w.slice_i], out=out)
 
-    def load_window_obs(self, w: Window, obs_start: int,
-                        obs_end: int) -> np.ndarray:
+    def load_window_obs(self, w: Window, obs_start: int, obs_end: int,
+                        out: np.ndarray | None = None) -> np.ndarray:
         """One window restricted to the observation range ``[obs_start,
         obs_end)`` — ``load_window`` is the full range. The restricted form
         is the streaming delta read: an incremental update touches only the
         chunks of the appended layers, O(new data) bytes, never the base
-        cube (streaming/incremental.py)."""
+        cube (streaming/incremental.py). ``out``: a C-contiguous float32
+        ``(num_points, obs_end - obs_start)`` array to read into (returned),
+        else a fresh one is made."""
         geom = self.geometry
         if not (0 <= w.slice_i < geom.num_slices
                 and 0 <= w.line_start < w.line_end <= geom.lines_per_slice):
@@ -448,8 +546,19 @@ class FileCubeSource:
                 f"observation range [{obs_start}, {obs_end}) outside the "
                 f"slice's [0, {slice_obs})")
         width = obs_end - obs_start
-        out = np.empty((w.num_lines, geom.points_per_line, width),
-                       dtype=np.float32)
+        shape = (w.num_lines * geom.points_per_line, width)
+        if out is None:
+            out = np.empty(shape, dtype=np.float32)
+        elif (out.shape != shape or out.dtype != np.float32
+              or not out.flags.c_contiguous or not out.flags.writeable):
+            raise ValueError(
+                f"out must be a writeable C-contiguous float32 array of "
+                f"shape {shape} for window {w} obs [{obs_start}, {obs_end}); "
+                f"got {out.dtype} {out.shape}")
+        dst = out.reshape(w.num_lines, geom.points_per_line, width)
+        row_bytes = geom.points_per_line * width * 4
+        whole = memoryview(dst).cast("B")
+        pieces = []  # (file, offset, destination bytes, name): positional
         for entry in self._chunks.get(w.slice_i, ()):
             o0, o1 = chunk_obs_range(entry, self.num_observations)
             if o1 <= obs_start or o0 >= obs_end:
@@ -458,15 +567,31 @@ class FileCubeSource:
                 continue
             lo = max(w.line_start, entry["line_start"])
             hi = min(w.line_end, entry["line_end"])
+            if (o0, o1) == (obs_start, obs_end) and not self.verify_reads:
+                f, offset = self._open(entry)
+                pieces.append((
+                    f, offset + (lo - entry["line_start"]) * row_bytes,
+                    whole[(lo - w.line_start) * row_bytes:
+                          (hi - w.line_start) * row_bytes],
+                    self.path / entry["file"]))
+                continue
             co0 = max(o0, obs_start)
             co1 = min(o1, obs_end)
             src = (self._read_chunk_verified(entry) if self.verify_reads
                    else self._mmap(entry))
-            out[lo - w.line_start : hi - w.line_start, :,
+            dst[lo - w.line_start : hi - w.line_start, :,
                 co0 - obs_start : co1 - obs_start] = src[
                 lo - entry["line_start"] : hi - entry["line_start"], :,
                 co0 - o0 : co1 - o0]
-        return out.reshape(w.num_lines * geom.points_per_line, width)
+        if pieces:
+            first, second = _halves(pieces, sum(len(p[2]) for p in pieces))
+            other = self._helper.submit(_read_pieces, second) if second else None
+            try:
+                _read_pieces(first)
+            finally:
+                if other is not None:
+                    other.result()
+        return out
 
     def verify(self) -> None:
         """Re-hash every chunk against the manifest; raises on the first

@@ -2,6 +2,8 @@
 prefetch overlap + error propagation, async persist / resume, slice
 scheduling across shards."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -238,3 +240,112 @@ def test_scheduler_single_shard_mode(sim):
     )
     # shard 1 owns slices [2, 4] under round-robin of [1,2,3,4]
     assert set(results) == {2, 4}
+
+
+# -- recycled read buffers -----------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def file_cube(sim, tmp_path_factory):
+    """The module's simulation exported in chunks of 4 lines, so 3-line
+    windows straddle chunk boundaries."""
+    from repro.data.file_source import export_cube
+
+    d = tmp_path_factory.mktemp("file_cube")
+    export_cube(sim, d, lines_per_chunk=4)
+    return d
+
+
+class _Recycling(StagedExecutor):
+    """Counts the host read buffers it makes. Unless ``aligned``, each lies
+    off a 64-byte boundary: the CPU runtime then copies out of it instead
+    of aliasing it, so the free-list is really exercised here. An aligned
+    one the CPU runtime makes the device array's own storage, and the
+    executor must then keep it off the free-list."""
+
+    aligned = False
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.made = []  # appended from the loading threads
+
+    def _new_buffer(self, size):
+        raw = np.empty(size + 16, np.float32)
+        k = next(k for k in range(16)
+                 if ((raw.ctypes.data + 4 * k) % 64 == 0) == self.aligned)
+        self.made.append(raw[k:k + size])
+        return self.made[-1]
+
+
+class _SlowOnce:
+    """Delays the first read of one window, so a straggler speculation
+    fires (FileCubeSource with ``out`` kept: it still takes buffers)."""
+
+    def __init__(self, inner, window, seconds):
+        self.inner, self.window, self.seconds = inner, window, seconds
+        self.geometry = inner.geometry
+        self.slice_observations = inner.slice_observations
+
+    def load_window(self, w, out=None):
+        if w == self.window and self.seconds:
+            seconds, self.seconds = self.seconds, 0
+            time.sleep(seconds)
+        return self.inner.load_window(w, out=out)
+
+
+@pytest.mark.parametrize("mode", ["prefetch", "serial", "speculate"])
+def test_recycled_buffers_bitwise_identical(sim, tree, file_cube, mode):
+    from repro.data.file_source import FileCubeSource
+
+    cfg = PDFConfig(window_lines=3, method="grouping_ml")
+    slices = [0, 1, 2, 3]
+    ref = StagedExecutor(cfg, sim, tree=tree, exec_config=SERIAL).run(
+        build_plan(sim.geometry, slices, 3))
+    src = FileCubeSource(file_cube)
+    ec = {"prefetch": ExecutorConfig(speculate=False),
+          "serial": SERIAL,
+          "speculate": ExecutorConfig(prefetch=False, speculate=True,
+                                      straggler_grace_s=0.05,
+                                      retry_backoff_s=0.001)}[mode]
+    if mode == "speculate":
+        # the 9th unit: the trailing load median exists by then
+        src = _SlowOnce(src, build_plan(sim.geometry, slices, 3).units[8].window, 1.0)
+    ex = _Recycling(cfg, src, tree=tree, exec_config=ec)
+    got = ex.run(build_plan(sim.geometry, slices, 3))
+    for s in slices:
+        assert_results_equal(ref[s], got[s])
+    rep = ex.last_report
+    assert rep.counters["read_recycled"] > 0
+    if mode == "speculate":
+        assert rep.speculations > 0
+        assert len(ex.made) >= 2  # the straggler's two loads, two buffers
+
+
+@pytest.mark.parametrize("aligned", [False, True])
+def test_recycled_buffer_never_overwritten_before_landing(sim, file_cube, aligned):
+    """Depth 1 and many one-line windows: each staged window, as the compute
+    stage takes it, equals a fresh read; and every read but those into a new
+    buffer landed in a recycled one."""
+    from repro.data.file_source import FileCubeSource
+
+    src = FileCubeSource(file_cube)
+    mismatched = []
+
+    class Checked(_Recycling):
+        def _compute_window(self, item, attempt=0):
+            if not np.array_equal(np.asarray(item.values),
+                                  src.load_window(item.unit.window)):
+                mismatched.append(item.unit.window)
+            return super()._compute_window(item, attempt)
+
+    Checked.aligned = aligned
+    cfg = PDFConfig(window_lines=1, method="grouping")
+    ex = Checked(cfg, FileCubeSource(file_cube),
+                 exec_config=ExecutorConfig(prefetch_depth=1, speculate=False))
+    ex.run(build_plan(sim.geometry, list(range(8)), 1))
+    assert mismatched == []
+    c = ex.last_report.counters
+    assert c["windows"] == 8 * sim.geometry.lines_per_slice
+    assert c.get("read_recycled", 0) == c["windows"] - len(ex.made)
+    if not aligned:
+        assert len(ex.made) == 1

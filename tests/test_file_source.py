@@ -285,3 +285,116 @@ def test_versioned_manifest_reads(tmp_path):
     assert read_manifest(d, version=2)["version"] == 2
     with pytest.raises(ValueError, match="no version 7"):
         read_manifest(d, version=7)
+
+
+@pytest.fixture(scope="module")
+def appended(tmp_path_factory):
+    """A format-2 cube: SIM_SOURCE with 5 observations appended to slice 0
+    (chunks of 4 lines, so slice 0 has a base and a delta layer)."""
+    from repro.streaming import append_realizations
+
+    d = tmp_path_factory.mktemp("appended")
+    export_cube(SIM_SOURCE, d, lines_per_chunk=4)
+    rng = np.random.default_rng(3)
+    block = rng.standard_normal((SIM_SOURCE.lines_per_slice,
+                                 SIM_SOURCE.points_per_line, 5), np.float32)
+    append_realizations(d, {0: block})
+    return d, block
+
+
+def _strided(src, w, lo, hi):
+    """The window's observations [lo, hi) through the memmap path: each
+    half is an observation sub-range, which no chunk row matches."""
+    mid = (lo + hi) // 2
+    return np.concatenate([src.load_window_obs(w, lo, mid),
+                           src.load_window_obs(w, mid, hi)], axis=1)
+
+
+BASE = SIM_SOURCE.observations
+POSITIONAL_CASES = {
+    # name: (cube, window, obs range or None for load_window, positional?)
+    "straddles_chunk_boundary": ("cube", Window(1, 2, 7), None, True),
+    "short_last_window": ("cube", Window(2, 8, 9), None, True),
+    "inside_one_chunk": ("cube", Window(3, 4, 7), None, True),
+    "format2_untouched_slice_full_range": ("appended", Window(1, 1, 6), None, True),
+    "format2_delta_layer": ("appended", Window(0, 2, 7), (BASE, BASE + 5), True),
+    "format2_appended_slice_full_range": ("appended", Window(0, 2, 7), None, False),
+    "format2_obs_sub_range": ("appended", Window(0, 0, 9), (3, BASE + 2), False),
+    "out_wrong_shape": ("cube", Window(1, 2, 7), None, "shape"),
+    "out_wrong_dtype": ("cube", Window(1, 2, 7), None, "dtype"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(POSITIONAL_CASES))
+def test_positional_reads_match_memmap_path(cube, appended, case):
+    """Where the requested observations are a chunk's whole row width, the
+    window is read by positional reads into ``out`` and no chunk memmap is
+    opened; else the strided memmap path runs. Both give the same bytes,
+    and ``out`` of the wrong shape or dtype is refused."""
+    which, w, obs, positional = POSITIONAL_CASES[case]
+    d = cube[2] if which == "cube" else appended[0]
+    src = FileCubeSource(d)
+    lo, hi = obs or (0, src.slice_observations(w.slice_i))
+    shape = (w.num_lines * SIM_SOURCE.points_per_line, hi - lo)
+    if positional in ("shape", "dtype"):
+        bad = (np.empty((shape[0] - 1, shape[1]), np.float32)
+               if positional == "shape" else np.empty(shape, np.float64))
+        with pytest.raises(ValueError, match="out must be"):
+            src.load_window(w, out=bad)
+        return
+    out = np.full(shape, np.nan, np.float32)
+    got = (src.load_window(w, out=out) if obs is None
+           else src.load_window_obs(w, lo, hi, out=out))
+    assert got is out
+    assert (not src._mmaps) == positional  # no memmap left open
+    want = _strided(FileCubeSource(d), w, lo, hi)
+    np.testing.assert_array_equal(got, want)
+    if which == "appended" and w.slice_i == 0:
+        delta = appended[1][w.line_start:w.line_end].reshape(shape[0], -1)
+        full = np.concatenate([FileCubeSource(d).load_window_obs(w, 0, BASE),
+                               delta], axis=1)
+        np.testing.assert_array_equal(got, full[:, lo:hi])
+    # and without ``out``: a fresh array with the same bytes
+    fresh = (FileCubeSource(d).load_window(w) if obs is None
+             else FileCubeSource(d).load_window_obs(w, lo, hi))
+    np.testing.assert_array_equal(fresh, want)
+
+
+def test_concurrent_positional_reads_with_evictions(cube, monkeypatch):
+    """More reader threads than cores, a descriptor LRU of 2 so chunk files
+    are evicted while other threads still read them, and a short switch
+    interval: every read still returns the exported bytes."""
+    import sys
+    import threading
+
+    from repro.data import file_source
+
+    sim_spec, _, d = cube
+    sim = build_source(sim_spec)
+    monkeypatch.setattr(file_source, "_MMAP_CACHE_SIZE", 2)
+    src = FileCubeSource(d)
+    windows = [Window(s, lo, min(lo + 3, 9)) for s in range(4) for lo in range(0, 9, 2)]
+    want = {w: sim.load_window(w) for w in windows}
+    bad, done = [], []
+
+    def reader(k):
+        rng = np.random.default_rng(k)
+        for i in rng.permutation(len(windows) * 3) % len(windows):
+            w = windows[i]
+            if not np.array_equal(src.load_window(w), want[w]):
+                bad.append(w)
+        done.append(k)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=reader, args=(k,)) for k in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert sorted(done) == list(range(16)) and bad == []
+    assert len(src._files) <= 2
