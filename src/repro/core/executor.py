@@ -34,14 +34,17 @@ Each step is a named span (``runtime.monitor.SpanRecorder``, one per
 executor) carrying the window's ``slice`` and ``line``: ``pdf.load.read``
 and ``pdf.load.h2d`` on the prefetch thread, ``pdf.persist.write`` on the
 writer thread, and on the main thread ``pdf.slice.open``,
-``pdf.load.wait``, ``pdf.moments``, ``pdf.select``, ``pdf.fit.launch``,
-``pdf.fit.wait``, ``pdf.handoff`` and ``pdf.slice.drain``. Main-thread spans
-never nest (with prefetch off, the load spans run on the main thread,
-inside ``pdf.load.wait``). The heartbeats and the report's stage totals take their times
-from the spans' own clock reads; ``ExecutorReport.spans`` and ``counters``
-carry the run's span totals and work counts (``windows``, ``points``,
-``fit_rows``, ``fit_rows_padded``, ``groups``, ``bytes_read``,
-``bytes_h2d``, ``read_recycled``).
+``pdf.load.first`` (the wait for a run's first window, which no compute
+overlaps), ``pdf.load.wait`` (the wait for each later window and for the
+stream's end), ``pdf.moments``, ``pdf.select``, ``pdf.fit.launch``,
+``pdf.fit.wait``, ``pdf.handoff`` and ``pdf.slice.drain``. Both waits count
+in ``wait_seconds``. Main-thread spans never nest (with prefetch off, the
+load spans run on the main thread, inside the wait). The heartbeats and
+the report's stage totals take their times from the spans' own clock
+reads; ``ExecutorReport.spans`` and ``counters`` carry the run's span
+totals and work counts (``windows``, ``points``, ``fit_rows``,
+``fit_rows_padded``, ``groups``, ``bytes_read``, ``bytes_h2d``,
+``read_recycled``).
 
 ``PDFComputer`` (pipeline.py) is a thin facade over this executor; the
 multi-slice entry point is ``run`` on a ``regions.Plan``, which
@@ -1315,7 +1318,8 @@ class StagedExecutor:
             while True:
                 wait_ids = (_ids(units[k].window) if k < len(units)
                             else edge_ids)
-                with self.spans.span("pdf.load.wait", **wait_ids) as wait:
+                wait_name = "pdf.load.first" if k == 0 and units else "pdf.load.wait"
+                with self.spans.span(wait_name, **wait_ids) as wait:
                     try:
                         item = next(stream, None)
                     except PrefetchError as pe:

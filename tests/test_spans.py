@@ -8,6 +8,7 @@ another."""
 import time
 
 import jax
+import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
@@ -16,8 +17,8 @@ from repro.api import (ComputeSpec, ExecSpec, MethodSpec, PDFSession,
 from repro.runtime.monitor import SpanRecorder, merge_totals
 
 MAIN_SPANS = {"pdf.session.open", "pdf.executor.build", "pdf.slice.open",
-              "pdf.load.wait", "pdf.moments", "pdf.select", "pdf.fit.launch",
-              "pdf.fit.wait", "pdf.handoff", "pdf.slice.drain"}
+              "pdf.load.first", "pdf.load.wait", "pdf.moments", "pdf.select",
+              "pdf.fit.launch", "pdf.fit.wait", "pdf.handoff", "pdf.slice.drain"}
 PREFETCH_SPANS = {"pdf.load.read", "pdf.load.h2d"}
 WRITER_SPANS = {"pdf.persist.write"}
 
@@ -117,7 +118,7 @@ def test_report_fields_are_the_spans_reads(tmp_path, tree, method):
 
     for ws in windows:
         (read,), (h2d,), (wait,) = spans("pdf.load.read", ws), spans("pdf.load.h2d", ws), \
-            spans("pdf.load.wait", ws)
+            spans("pdf.load.first", ws) + spans("pdf.load.wait", ws)
         assert h2d.start == read.end
         assert ws.load_seconds == h2d.end - read.start
         assert ws.wait_seconds == wait.seconds
@@ -148,6 +149,39 @@ def test_report_fields_are_the_spans_reads(tmp_path, tree, method):
                                      if n in sr.spans)
 
 
+@pytest.fixture(scope="module")
+def default_results(tmp_path_factory, tree):
+    return list(PDFSession(spec(tmp_path_factory.mktemp("default")), tree=tree).run())
+
+
+@pytest.mark.parametrize("prefetch", [True, False])
+def test_first_window_wait_has_its_own_span(tmp_path, tree, default_results, prefetch):
+    # each run's (each slice's) wait for its first window is pdf.load.first;
+    # it still counts in the window's and the report's wait_seconds
+    session = PDFSession(spec(tmp_path, prefetch=prefetch), tree=tree)
+    ex = session.executor()
+    ex.spans = LoggingRecorder()
+    results = list(session.run())
+    rep = session.report()
+    runs = rep.spans["pdf.slice.open"][1]
+    assert runs == 2 and rep.spans["pdf.load.first"][1] == runs
+    firsts = [(ids["slice"], ids["line"]) for n, ids, _sp in ex.spans.log
+              if n == "pdf.load.first"]
+    assert firsts == [(0, 0), (1, 0)]
+    windows = [ws for r in results for ws in r.stats]
+    waits = {(ids["slice"], ids["line"]): sp.seconds for n, ids, sp in ex.spans.log
+             if n in ("pdf.load.first", "pdf.load.wait") and ids["line"] >= 0}
+    assert len(waits) == len(windows)
+    for ws in windows:
+        assert ws.wait_seconds == waits[(ws.window.slice_i, ws.window.line_start)]
+    assert rep.wait_seconds == pytest.approx(sum(ws.wait_seconds for ws in windows))
+    last = [ws for ws in windows if ws.window.slice_i == 1]
+    assert ex.last_report.wait_seconds == pytest.approx(sum(ws.wait_seconds for ws in last))
+    for got, want in zip(results, default_results):
+        for name in ("type_idx", "params", "error", "mean", "std", "skew", "kurt"):
+            np.testing.assert_array_equal(getattr(got, name), getattr(want, name))
+
+
 def test_session_report_sums_spans_and_counters(tmp_path, tree):
     session = PDFSession(spec(tmp_path), tree=tree)
     for _ in session.run():
@@ -163,7 +197,7 @@ def test_session_report_sums_spans_and_counters(tmp_path, tree):
                 "pdf.slice.drain": 2, "pdf.moments": 6, "pdf.select": 6,
                 "pdf.fit.launch": 6, "pdf.fit.wait": 6, "pdf.handoff": 6,
                 "pdf.load.read": 6, "pdf.load.h2d": 6, "pdf.persist.write": 6,
-                "pdf.load.wait": 8}
+                "pdf.load.first": 2, "pdf.load.wait": 6}
     assert {k: n for k, (_s, n) in rep.spans.items()} == per_call
     totals = {}
     for reports in rep.shard_reports.values():
@@ -192,13 +226,31 @@ def test_profiled_run_names_each_thread(tmp_path, tree):
                    for e in line.events if e.name.startswith("pdf.")]
             if evs:
                 lines.append(evs)
-    # one main thread; a prefetch and a writer thread per slice
+    # one main thread; a prefetch and a writer thread per slice. Each line
+    # holds one role's spans, except where the OS gave a finished thread's id
+    # to a later slice's thread of the other role: then the line is two runs
+    # of spans, one role each, the first ending before the second starts and
+    # of earlier slices only
+    role_of = {**{n: "main" for n in MAIN_SPANS}, **{n: "prefetch" for n in PREFETCH_SPANS},
+               **{n: "writer" for n in WRITER_SPANS}}
     roles = {"main": [], "prefetch": [], "writer": []}
     for evs in lines:
         names = {n for n, *_ in evs}
         role = ("main" if names == MAIN_SPANS else "prefetch" if names == PREFETCH_SPANS
-                else "writer" if names == WRITER_SPANS else names)
-        roles[role] += evs
+                else "writer" if names == WRITER_SPANS else None)
+        if role is None:
+            assert names == PREFETCH_SPANS | WRITER_SPANS, names
+            evs = sorted(evs, key=lambda e: e[2])
+            cut = next(i for i, e in enumerate(evs) if role_of[e[0]] != role_of[evs[0][0]])
+            first, second = evs[:cut], evs[cut:]
+            assert len({role_of[n] for n, *_ in second}) == 1, evs
+            assert max(e[3] for e in first) <= second[0][2], evs
+            assert max(ids["slice"] for _n, ids, *_ in first) < \
+                min(ids["slice"] for _n, ids, *_ in second), evs
+            for run in (first, second):
+                roles[role_of[run[0][0]]] += run
+        else:
+            roles[role] += evs
     assert sum(1 for evs in lines if {n for n, *_ in evs} == MAIN_SPANS) == 1
     main = sorted(roles["main"], key=lambda e: e[2])
     for a, b in zip(main, main[1:]):  # leaves: each ends before the next starts
