@@ -12,7 +12,14 @@ is that layer for the JAX reproduction:
                  A source that reads into ``out`` (FileCubeSource) fills a
                  host buffer recycled from the executor's free-list; the
                  load waits for that window's transfer to land before the
-                 buffer goes back.
+                 buffer goes back. A window of two ``_MIN_PIECE_BYTES`` or
+                 more bound for one device, from any source, is staged in
+                 pieces of whole lines: each piece's transfer is enqueued
+                 before the next piece is read, so it runs under that read,
+                 and the pieces are assembled into the window on the
+                 device (a concatenate, bitwise the one-piece window)
+                 before the buffer goes back. Smaller windows, a sharding
+                 over several devices and ``run_window`` stage in one piece.
   compute stage  the main thread: Select (grouping / reuse / ML dispatch)
                  on host + batched ComputePDF&Error on device — identical
                  operations, in identical order, to the old serial loop, so
@@ -44,7 +51,11 @@ the report's stage totals take their times from the spans' own clock
 reads; ``ExecutorReport.spans`` and ``counters`` carry the run's span
 totals and work counts (``windows``, ``points``, ``fit_rows``,
 ``fit_rows_padded``, ``groups``, ``bytes_read``, ``bytes_h2d``,
-``read_recycled``).
+``read_recycled``, ``load_pieces``: the pieces each window was staged in,
+1 for a window staged whole). In a window staged in pieces,
+``pdf.load.read`` holds the piece reads and their transfers' enqueues, and
+``pdf.load.h2d`` only what the reads did not hide: the last piece's
+transfer and the assembly, to the assembled window's landing.
 
 ``PDFComputer`` (pipeline.py) is a thin facade over this executor; the
 multi-slice entry point is ``run`` on a ``regions.Plan``, which
@@ -508,6 +519,32 @@ class _FailedUnit(NamedTuple):
 # loading thread (the prefetcher, or a speculation pair).
 _FREE_BUFFERS = 4
 
+# The least bytes of a window's piece when the load stages it in pieces of
+# whole lines (_load_unit): each piece's transfer runs while the next piece
+# is read, so only the last piece's transfer and the on-device assembly
+# stay in the load's path. A window under twice this takes one piece, so
+# Set1's 25 MB windows and all one-line tails stage whole. On a v5e host a
+# 251 MB Set3 window took 88.5 ms to read and land whole, 70-72 ms in 3 to
+# 7 pieces; the unhidden tail fell from 11.1 ms (3) to 6.6 ms (7, this
+# size: pieces of ~36 MB). The enqueue itself costs ~0.4 ms (PERF.md §6).
+_MIN_PIECE_BYTES = 32 << 20
+
+
+@jax.jit
+def _assemble(*pieces: jax.Array) -> jax.Array:
+    """A window's line pieces, back in one ``(points, observations)`` array
+    on their device: data movement only, so bitwise the window staged
+    whole."""
+    return jnp.concatenate(pieces, axis=0)  # repro: allow[SHAPE]: rebuilds the window's own (P, n) shape from its line pieces — data movement, no arithmetic to drift
+
+
+def _piece_lines(num_lines: int, nbytes: int) -> list[int]:
+    """Line bounds of the pieces a window of ``nbytes`` is staged in: as
+    many as whole ``_MIN_PIECE_BYTES`` fit, at most one a line, at least
+    one."""
+    k = max(1, min(nbytes // _MIN_PIECE_BYTES, num_lines))
+    return [num_lines * i // k for i in range(k + 1)]
+
 
 def _takes_out(source) -> bool:
     """Whether ``source.load_window`` reads into a caller's ``out`` (an
@@ -844,6 +881,9 @@ class StagedExecutor:
         # (FileCubeSource): flat float32, each back on this list only once
         # the transfer staged from it has landed (_load_unit).
         self._fills_out = _takes_out(data_source)
+        # Pieces are staged only to one device (a wider sharding would
+        # split each piece across devices, not the window).
+        self._one_device = sharding is None or len(sharding.device_set) == 1
         self._free_buffers: list[np.ndarray] = []
         self._free_lock = threading.Lock()
 
@@ -862,25 +902,43 @@ class StagedExecutor:
     def _new_buffer(self, size: int) -> np.ndarray:
         return np.empty(size, np.float32)
 
-    def _read(self, w: regions.Window) -> tuple[np.ndarray | None, np.ndarray]:
-        """``(buffer, window)``: the window read into a host buffer taken
-        from the free-list (counted ``read_recycled``) or made anew, where
-        the source reads into ``out``; else ``(None, fresh array)``."""
-        if not self._fills_out:
-            return None, self.data.load_window(w)  # (P, n_obs)
-        shape = (w.num_lines * self.data.geometry.points_per_line,
-                 self.data.slice_observations(w.slice_i))
-        size = shape[0] * shape[1]
-        with self._free_lock:
-            fits = [i for i, b in enumerate(self._free_buffers) if b.size >= size]
-            buf = self._free_buffers.pop(fits[-1]) if fits else None
-        recycled = buf is not None
-        if buf is None:
-            buf = self._new_buffer(size)
-        raw = self.data.load_window(w, out=buf[:size].reshape(shape))
+    def _read(self, w: regions.Window, whole: bool = False
+              ) -> tuple[np.ndarray | None, np.ndarray, list[jax.Array]]:
+        """``(buffer, window, pieces)``. Where the source reads into ``out``,
+        the window lands in a host buffer taken from the free-list (counted
+        ``read_recycled``) or made anew; else ``buffer`` is None and the
+        window a fresh array. A window of two ``_MIN_PIECE_BYTES`` or more,
+        bound for one device and not ``whole``, is read in pieces of whole
+        lines, each piece's transfer enqueued before the next piece is read:
+        ``pieces`` are those staged arrays, else empty (the caller stages
+        the window whole)."""
+        ppl = self.data.geometry.points_per_line
+        buf, recycled = None, False
+        if self._fills_out:
+            shape = (w.num_lines * ppl, self.data.slice_observations(w.slice_i))
+            size = shape[0] * shape[1]
+            with self._free_lock:
+                fits = [i for i, b in enumerate(self._free_buffers) if b.size >= size]
+                buf = self._free_buffers.pop(fits[-1]) if fits else None
+            recycled = buf is not None
+            if buf is None:
+                buf = self._new_buffer(size)
+            raw = buf[:size].reshape(shape)
+        else:
+            raw = self.data.load_window(w)  # (P, n_obs)
+        bounds = ([0, w.num_lines] if whole or not self._one_device
+                  else _piece_lines(w.num_lines, raw.nbytes))
+        pieces = []
+        for lo, hi in zip(bounds, bounds[1:]):
+            piece = raw[lo * ppl:hi * ppl]
+            if buf is not None:
+                self.data.load_window(regions.Window(
+                    w.slice_i, w.line_start + lo, w.line_start + hi), out=piece)
+            if len(bounds) > 2:
+                pieces.append(self._stage(piece))
         if recycled:
             self.spans.count("read_recycled")
-        return buf, raw
+        return buf, raw, pieces
 
     def _give_back(self, buf: np.ndarray, staged: jax.Array) -> None:
         """Return ``buf`` to the free-list once ``staged``'s transfer has
@@ -895,29 +953,34 @@ class StagedExecutor:
             if len(self._free_buffers) < _FREE_BUFFERS:
                 self._free_buffers.append(buf)
 
-    def _load_unit(self, unit: regions.WorkUnit,
-                   uid: str | None = None) -> _StagedWindow:
+    def _load_unit(self, unit: regions.WorkUnit, uid: str | None = None,
+                   whole: bool = False) -> _StagedWindow:
         """Load + H2D-stage one window (host work only — device kernels stay
         on the compute stage); runs on the prefetch thread when prefetch is
         enabled, or on speculation-pool threads under re-dispatch. ``uid``
         distinguishes attempts of the same unit in the load monitor; failed
         attempts are abandoned (no duration recorded) so an injected stall
-        cannot poison the straggler median. A window read into a recycled
-        buffer waits here for its transfer to land (inside ``pdf.load.h2d``)
-        before the buffer goes back; two loads of one window (speculation)
-        take two buffers."""
+        cannot poison the straggler median. A large window is read in line
+        pieces whose transfers run under the next piece's read (``_read``;
+        ``whole`` stages it in one); the pieces are assembled on the device
+        inside ``pdf.load.h2d``, which then holds only the transfer's
+        unhidden tail. A window read into a recycled buffer, or in pieces,
+        waits there for its staged array to land before the buffer goes
+        back; two loads of one window (speculation) take two buffers."""
         mon = self.monitors["load"]
         uid = uid or unit.unit_id
         ids = _ids(unit.window)
         try:
             with self.spans.span("pdf.load.read", **ids) as read:
                 mon.start(uid, now=read.start)
-                buf, raw = self._read(unit.window)
+                buf, raw, pieces = self._read(unit.window, whole)
             self.spans.count("bytes_read", raw.nbytes)
+            self.spans.count("load_pieces", max(1, len(pieces)))
             with self.spans.span("pdf.load.h2d", start=read.end, **ids) as h2d:
-                values = self._stage(raw)
-                if buf is not None:
+                values = _assemble(*pieces) if pieces else self._stage(raw)
+                if buf is not None or pieces:
                     values.block_until_ready()
+                if buf is not None:
                     self._give_back(buf, values)
         except BaseException:
             mon.abandon(uid)
@@ -1775,7 +1838,7 @@ class StagedExecutor:
         → moments → Select & fit), without persist: the per-window fallback
         of ``run_window_batch`` (method='sampling') and the serving layer's
         naive one-launch-per-query baseline."""
-        item = self._load_unit(regions.WorkUnit(w, 0))
+        item = self._load_unit(regions.WorkUnit(w, 0), whole=True)
         values = item.values
         total_points = values.shape[0]
         sample_idx = None

@@ -349,3 +349,159 @@ def test_recycled_buffer_never_overwritten_before_landing(sim, file_cube, aligne
     assert c.get("read_recycled", 0) == c["windows"] - len(ex.made)
     if not aligned:
         assert len(ex.made) == 1
+
+
+# -- windows staged in line pieces ---------------------------------------------
+
+# One line of the module's cube: 12 points x 250 observations, float32.
+LINE_BYTES = 12 * 250 * 4
+
+
+MB = 10**6
+
+
+@pytest.mark.parametrize("lines, nbytes, expected", [
+    (25, 251 * MB, [0, 5, 10, 15, 20, 25]),  # a Set3 window in 5 pieces
+    (25, 2 * 50 * MB - 1, [0, 25]),          # under twice the constant: one
+    (25, 2 * 50 * MB, [0, 12, 25]),
+    (3, 100 * 50 * MB, [0, 1, 2, 3]),        # at most one piece a line
+])
+def test_piece_lines(monkeypatch, lines, nbytes, expected):
+    from repro.core import executor
+
+    monkeypatch.setattr(executor, "_MIN_PIECE_BYTES", 50 * MB)
+    assert executor._piece_lines(lines, nbytes) == expected
+
+
+def test_piece_constant_engages_only_on_set3_full_windows():
+    from repro.core import executor
+
+    # Set1's full windows (25 lines x 251 points x 1,000 obs) and one-line
+    # tails at Set1 and Set3 take one piece; Set3's full windows 3-8
+    line = {"set1": 251 * 1000 * 4, "set3": 251 * 10000 * 4}
+    assert executor._piece_lines(25, 25 * line["set1"]) == [0, 25]
+    assert executor._piece_lines(1, line["set1"]) == [0, 1]
+    assert executor._piece_lines(1, line["set3"]) == [0, 1]
+    assert 4 <= len(executor._piece_lines(25, 25 * line["set3"])) <= 9
+
+
+def _persisted(out_dir):
+    return {f.name: dict(np.load(f)) for f in sorted(out_dir.glob("*.npz"))}
+
+
+class _Checked(StagedExecutor):
+    """Records each staged window, as the compute stage takes it, that
+    differs from a fresh read of the window."""
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.mismatched = []
+
+    def _compute_window(self, item, attempt=0):
+        w = item.unit.window
+        if not np.array_equal(np.asarray(item.values), self.data.load_window(w)):
+            self.mismatched.append(w)
+        return super()._compute_window(item, attempt)
+
+
+@pytest.mark.parametrize("piece_lines", [1, 2])
+@pytest.mark.parametrize("source", ["file", "memory"])
+def test_pieces_bitwise_identical(sim, tree, file_cube, tmp_path, monkeypatch,
+                                  source, piece_lines):
+    """5-line windows (then a 4-line tail) staged in pieces of one line, or
+    of 2 (5 lines split 2 + 3): the staged windows, the results and the
+    persisted outputs are bitwise those of the one-piece path."""
+    from repro.core import executor
+    from repro.data.file_source import FileCubeSource
+
+    cfg = PDFConfig(window_lines=5, method="grouping_ml")
+    slices = [0, 1, 2]
+
+    def run(out):
+        src = FileCubeSource(file_cube) if source == "file" else sim
+        ex = _Checked(cfg, src, tree=tree, out_dir=tmp_path / out,
+                      exec_config=ExecutorConfig(speculate=False))
+        return ex, ex.run(build_plan(sim.geometry, slices, 5))
+
+    whole, ref = run("whole")
+    monkeypatch.setattr(executor, "_MIN_PIECE_BYTES", piece_lines * LINE_BYTES)
+    pieced, got = run("pieces")
+    assert whole.mismatched == pieced.mismatched == []
+    for s in slices:
+        assert_results_equal(ref[s], got[s])
+    a, b = _persisted(tmp_path / "whole"), _persisted(tmp_path / "pieces")
+    assert a.keys() == b.keys() and len(a) == 2 * len(slices)
+    for name in a:
+        for k in a[name]:
+            np.testing.assert_array_equal(a[name][k], b[name][k], err_msg=k)
+    c = pieced.last_report.counters
+    assert c["bytes_h2d"] == c["bytes_read"] == whole.last_report.counters["bytes_read"]
+
+
+@pytest.mark.parametrize("piece_lines, pieces_per_slice", [
+    (1, 9),     # one piece a line: 5 + 4
+    (2, 4),     # 2 + 2
+    (2.5, 3),   # the 5-line window in 2, the 4-line tail (under 5 lines) in 1
+    (3, 2),     # both windows under twice the constant: one piece each
+])
+def test_load_pieces_counted(sim, file_cube, monkeypatch, piece_lines,
+                             pieces_per_slice):
+    from repro.core import executor
+    from repro.data.file_source import FileCubeSource
+
+    monkeypatch.setattr(executor, "_MIN_PIECE_BYTES", int(piece_lines * LINE_BYTES))
+    ex = StagedExecutor(PDFConfig(window_lines=5, method="grouping"),
+                        FileCubeSource(file_cube))
+    ex.run(build_plan(sim.geometry, [0, 1], 5))
+    c = ex.last_report.counters
+    assert c["windows"] == 4
+    assert c["load_pieces"] == 2 * pieces_per_slice
+
+
+def test_window_under_twice_the_constant_stages_one_piece(sim, file_cube, monkeypatch):
+    """Every window under two _MIN_PIECE_BYTES takes today's one-piece path:
+    no assembly, one piece counted a window."""
+    from repro.core import executor
+    from repro.data.file_source import FileCubeSource
+
+    def refuse(*pieces):
+        raise AssertionError("a one-piece window was assembled")
+
+    monkeypatch.setattr(executor, "_MIN_PIECE_BYTES", 5 * LINE_BYTES // 2 + 1)
+    monkeypatch.setattr(executor, "_assemble", refuse)
+    ex = StagedExecutor(PDFConfig(window_lines=5, method="grouping"),
+                        FileCubeSource(file_cube))
+    ex.run(build_plan(sim.geometry, [0], 5))
+    c = ex.last_report.counters
+    assert c["load_pieces"] == c["windows"] == 2
+    # the serving path stages whole whatever the window's size
+    monkeypatch.setattr(executor, "_MIN_PIECE_BYTES", LINE_BYTES)
+    from repro.core.regions import Window
+
+    assert ex.run_window(Window(0, 0, 5)).type_idx.shape == (5 * 12,)
+
+
+@pytest.mark.parametrize("aligned", [False, True])
+def test_pieced_buffer_never_recycled_before_landing(sim, file_cube, monkeypatch,
+                                                     aligned):
+    """Depth 1 and 3-line windows staged a line a piece: each staged window,
+    as the compute stage takes it, equals a fresh read; the assembled window
+    never lies in the host buffer, so one buffer serves every read."""
+    from repro.core import executor
+    from repro.data.file_source import FileCubeSource
+
+    monkeypatch.setattr(executor, "_MIN_PIECE_BYTES", LINE_BYTES)
+
+    class Both(_Checked, _Recycling):
+        pass
+
+    Both.aligned = aligned
+    ex = Both(PDFConfig(window_lines=3, method="grouping"), FileCubeSource(file_cube),
+              exec_config=ExecutorConfig(prefetch_depth=1, speculate=False))
+    ex.run(build_plan(sim.geometry, list(range(8)), 3))
+    assert ex.mismatched == []
+    c = ex.last_report.counters
+    assert c["windows"] == 8 * 3
+    assert c["load_pieces"] == 3 * c["windows"]
+    assert len(ex.made) == 1
+    assert c["read_recycled"] == c["windows"] - 1
